@@ -53,64 +53,6 @@ def _run(cfg, params, mode: str, steps: int = 400,
     return np.array(times) * 1e3
 
 
-def fused_kernel_gate(quick: bool, iters: int = 300, batch: int = 32,
-                      n_domains: int = 64) -> dict:
-    """Tentpole gate: wall-clock the fused Pallas enforcement kernel
-    against the lax scan reference at the same shape (mixed two-program
-    registry, like a busy engine).  On real TPUs the fused path must
-    not lose at P50; in interpret mode (CPU CI) the "kernel" is
-    emulated with traced jax ops, so only the numbers are reported."""
-    from repro import compat
-    from repro.analysis.roofline import enforcement_roofline
-    from repro.core import controller as C
-    from repro.core.cgroup import AgentCgroup, DeviceTableBackend, DomainSpec
-    from repro.core.progs import GraduatedThrottleProgram, TokenBucketProgram
-    from repro.kernels.enforcement import fused_charge_batch
-    import jax.numpy as jnp
-
-    cg = AgentCgroup(DeviceTableBackend(1 << 20, n_domains=n_domains))
-    cg.attach("/", GraduatedThrottleProgram())
-    cg.mkdir("/grad", DomainSpec(high=1000))
-    cg.mkdir("/bkt")
-    cg.attach("/bkt", TokenBucketProgram(bucket_capacity=64,
-                                         refill=(1.0, 1.0, 1.0)))
-    progs = cg.programs
-    st = cg.device_view().state
-    dom = jnp.array([cg.handle("/grad"), cg.handle("/bkt")] * (batch // 2),
-                    jnp.int32)
-    amt = jnp.ones((batch,), jnp.int32)
-    lax_j = jax.jit(lambda s, d, a: C._lax_charge_batch(s, d, a, 0, progs))
-    fused_j = jax.jit(lambda s, d, a: fused_charge_batch(s, d, a, 0, progs))
-
-    def p50(fn):
-        jax.block_until_ready(fn(st, dom, amt))          # warm the jit
-        times = []
-        for _ in range(iters):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(st, dom, amt))
-            times.append(time.perf_counter() - t0)
-        return float(np.percentile(np.array(times) * 1e3, 50))
-
-    lp, fp = p50(lax_j), p50(fused_j)
-    rl = enforcement_roofline(n_domains=n_domains, batch=batch)
-    print("\n== fused enforcement kernel vs lax scan "
-          f"(batch={batch}, {len(progs)} programs) ==")
-    print(f"charge_batch P50: lax {lp:.3f} ms | fused {fp:.3f} ms "
-          f"({(fp / lp - 1) * 100:+.1f}%)")
-    print(f"cost model: lax {rl['lax']['bytes']:.0f} B / "
-          f"{rl['lax']['flops']:.0f} flop, fused "
-          f"{rl['fused']['bytes']:.0f} B / {rl['fused']['flops']:.0f} flop")
-    if quick:
-        if compat.on_tpu():
-            assert fp <= lp, \
-                f"fused P50 {fp:.3f} ms > lax P50 {lp:.3f} ms on TPU"
-            print(f"fused-kernel gate OK (fused {fp:.3f} <= lax {lp:.3f})")
-        else:
-            print("fused-kernel gate: interpret mode, P50 assert skipped "
-                  "(the kernel is emulated off-TPU)")
-    return {"p50_lax_charge": lp, "p50_fused_charge": fp}
-
-
 def run(steps: int = 400, quick: bool = False, backend: str = "device"):
     cfg = dataclasses.replace(reduced(get_config("llama3.2-3b")),
                               dtype="float32")
@@ -151,7 +93,6 @@ def run(steps: int = 400, quick: bool = False, backend: str = "device"):
         ratio = p(core, 50) / p(off, 50)
         assert ratio < 2.0, f"in-step enforcement P50 ratio {ratio:.2f} >= 2"
         print(f"quick-mode smoke OK (ratio {ratio:.2f} < 2.0)")
-    out.update(fused_kernel_gate(quick, iters=60 if quick else 300))
     return out
 
 

@@ -1,21 +1,17 @@
 """End-to-end driver: multi-tenant agent serving with batched requests.
 
-Serves a reduced model to agent sessions derived from paper-calibrated
-traces (each tool call's result floods the context, the KV-page analogue
-of the paper's §3 memory bursts), under all three controller modes, and
-prints a Fig-8-style comparison.
+Serves the reduced float32 preset (a CPU-sized demonstration; the
+full-width model is ``repro.launch.serve`` on a TPU) to agent sessions
+derived from paper-calibrated traces (each tool call's result floods the
+context, the KV-page analogue of the paper's §3 memory bursts), under
+all three controller modes, and prints a Fig-8-style comparison.
 
 Run: PYTHONPATH=src python examples/serve_agents.py [--sessions 5]
 """
 import argparse
-import dataclasses
 
-import jax
-
-from repro.configs import get_config, reduced
 from repro.core import domains as D
-from repro.models import model as M
-from repro.models.schema import init_params
+from repro.launch.serve import build_model
 from repro.perf import DEFAULT_PERF, replace as perf_replace
 from repro.serving.engine import Engine, EngineConfig
 from repro.serving.session import session_from_trace
@@ -42,10 +38,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    cfg = dataclasses.replace(reduced(get_config(args.arch)),
-                              dtype="float32")
-    params = init_params(M.param_schema(cfg), jax.random.PRNGKey(args.seed),
-                         cfg.dtype)
+    cfg, params = build_model(args.arch, reduced_preset=True, seed=args.seed)
     perf = perf_replace(DEFAULT_PERF, scan_chunk=32)
     common = dict(max_slots=4, s_max=512, pool_pages=args.pool_pages,
                   page_tokens=16)

@@ -24,7 +24,7 @@ PRAGMA_RE = re.compile(
 # modules whose decision code must stay suppression-free: these are the
 # one-decision-path files every substrate traces (acceptance invariant)
 DECISION_MODULES = ("core/progs.py", "core/sched.py", "core/controller.py",
-                    "core/pressure.py", "kernels/enforcement.py")
+                    "core/pressure.py")
 
 META_RULE = "TL000"          # framework findings about suppressions
 

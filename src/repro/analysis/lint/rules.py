@@ -28,21 +28,16 @@ PROGRAM_BASES = frozenset({
 TRACED_HOOKS = frozenset({"on_charge", "on_over_high", "on_gate",
                           "on_schedule"})
 # module-level decision entry points in the decision-path modules —
-# the functions all six backend kinds trace verbatim.  The fused Pallas
-# kernel bodies and wrappers (kernels/enforcement.py) are included: the
-# kernel glue traces the same decision code and carries the same
-# purity obligation.  Python-time registry dispatch helpers
-# (``_single_prog``, the branch factories) are deliberately NOT roots —
-# their length checks run at trace time, never on traced values.
+# the functions all six backend kinds trace verbatim.  Python-time
+# registry dispatch helpers (``_single_prog``, the branch factories)
+# are deliberately NOT roots — their length checks run at trace time,
+# never on traced values.
 TRACED_FUNCS = frozenset({
     "charge_decision", "schedule_decision", "charge_batch", "slot_gate",
     "uncharge_batch", "_chain_view", "_ancestor_chain",
     "charge_stall_event", "sched_stall_events",
     "_decision_one", "gate_decision", "schedule_weight",
     "saturating_count",
-    "fused_charge_batch", "fused_slot_gate",
-    "_lax_charge_batch", "_lax_slot_gate",
-    "_charge_kernel", "_gate_kernel", "_view_state",
 })
 
 

@@ -13,11 +13,8 @@ The ratio MODEL_FLOPS / (HLO_FLOPs * chips) exposes remat/dispatch waste.
 """
 from __future__ import annotations
 
-import math
-from typing import Optional
-
 from repro.configs.base import ModelConfig, ShapeConfig
-from repro.launch.mesh import HW
+from repro.launch.mesh import peaks
 
 
 def model_flops(cfg: ModelConfig, shape: ShapeConfig) -> float:
@@ -30,22 +27,23 @@ def model_flops(cfg: ModelConfig, shape: ShapeConfig) -> float:
 
 
 def roofline_from_costs(cfg: ModelConfig, shape: ShapeConfig, parsed: dict,
-                        *, n_chips: int) -> dict:
+                        *, n_chips: int, device_kind: str) -> dict:
+    hw = peaks(device_kind)
     flops = parsed["flops"]                 # per device
     byts = parsed["bytes"]
     coll_total = parsed["coll_bytes_total"]
     dcn = parsed.get("coll_dcn_bytes", 0.0)
     ici = max(coll_total - dcn, 0.0)
-    compute_s = flops / HW["flops_bf16"]
-    memory_s = byts / HW["hbm_bw"]
-    collective_s = ici / HW["ici_bw"] + dcn / HW["dcn_bw"]
+    compute_s = flops / hw["flops_bf16"]
+    memory_s = byts / hw["hbm_bw"]
+    collective_s = ici / hw["ici_bw"] + dcn / hw["dcn_bw"]
     terms = {"compute_s": compute_s, "memory_s": memory_s,
              "collective_s": collective_s}
     dominant = max(terms, key=terms.get)
     mf = model_flops(cfg, shape)
     hlo_global = flops * n_chips
     step_s = max(compute_s, memory_s, collective_s)
-    ideal_s = mf / (n_chips * HW["flops_bf16"])
+    ideal_s = mf / (n_chips * hw["flops_bf16"])
     return {
         **{k: float(v) for k, v in terms.items()},
         "dominant": dominant,
@@ -57,64 +55,6 @@ def roofline_from_costs(cfg: ModelConfig, shape: ShapeConfig, parsed: dict,
         "roofline_fraction": (ideal_s / step_s) if step_s else 0.0,
         "step_time_bound_s": step_s,
     }
-
-
-def enforcement_roofline(n_domains: int = 64, batch: int = 32) -> dict:
-    """Roofline the fused Pallas enforcement kernel against the lax
-    scan reference at the same shape: compile both, read the XLA cost
-    model (flops / bytes accessed), and bound each with the HW table.
-
-    Both paths are compiled explicitly (``_lax_charge_batch`` vs
-    ``kernels.enforcement.fused_charge_batch``) so the numbers do not
-    depend on the runtime dispatch seam.  Off-TPU the fused kernel
-    compiles in interpret mode — its cost numbers then describe the
-    traced jax ops, which is still the apples-to-apples comparison the
-    gate in ``benchmarks/engine_overhead.py`` wall-clocks.  The hot
-    path is control-state sized (KBs, not GBs): both columns sit far
-    under the memory roofline, and the win the fused pass buys is
-    fewer HBM round-trips per request slot (``bytes_ratio``).
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from repro import compat
-    from repro.core import controller as C
-    from repro.core.cgroup import AgentCgroup, DeviceTableBackend, DomainSpec
-    from repro.core.progs import GraduatedThrottleProgram, TokenBucketProgram
-    from repro.kernels.enforcement import fused_charge_batch
-
-    cg = AgentCgroup(DeviceTableBackend(1 << 20, n_domains=n_domains))
-    cg.attach("/", GraduatedThrottleProgram())
-    cg.mkdir("/grad", DomainSpec(high=1000))
-    cg.mkdir("/bkt")
-    cg.attach("/bkt", TokenBucketProgram(bucket_capacity=64,
-                                         refill=(1.0, 1.0, 1.0)))
-    progs = cg.programs
-    view = cg.device_view()
-    dom = jnp.array([cg.handle("/grad"), cg.handle("/bkt")]
-                    * (batch // 2) + [cg.handle("/grad")] * (batch % 2),
-                    jnp.int32)
-    amt = jnp.ones((batch,), jnp.int32)
-
-    def lax_fn(st, d, a):
-        return C._lax_charge_batch(st, d, a, 0, progs)
-
-    def fused_fn(st, d, a):
-        return fused_charge_batch(st, d, a, 0, progs)
-
-    out: dict = {"n_domains": n_domains, "batch": batch,
-                 "n_programs": len(progs), "on_tpu": compat.on_tpu()}
-    for name, fn in (("lax", lax_fn), ("fused", fused_fn)):
-        compiled = jax.jit(fn).lower(view.state, dom, amt).compile()
-        ca = compat.cost_analysis(compiled)
-        flops = float(ca.get("flops", 0.0))
-        byts = float(ca.get("bytes accessed", 0.0))
-        out[name] = {"flops": flops, "bytes": byts,
-                     "compute_s": flops / HW["flops_bf16"],
-                     "memory_s": byts / HW["hbm_bw"]}
-    if out["lax"]["bytes"] and out["fused"]["bytes"]:
-        out["bytes_ratio"] = out["fused"]["bytes"] / out["lax"]["bytes"]
-    return out
 
 
 def fmt_seconds(s: float) -> str:

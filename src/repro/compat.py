@@ -1,123 +1,62 @@
-"""JAX version-compatibility shims (single choke point for API drift).
+"""Platform resolution: which device runs the program, how its Pallas
+kernels run there, and where compiled programs are cached.
 
-The repo targets the Pallas/sharding surface of recent JAX, but must run
-on every version the CI matrix installs (currently 0.4.37).  Three APIs
-moved between 0.4.x and 0.5+:
+Written against the installed JAX (0.9): call sites use
+``pltpu.CompilerParams``, ``jax.make_mesh(..., axis_types=...)``,
+``jax.shard_map`` and ``compiled.cost_analysis()`` directly.
 
-  * ``pltpu.CompilerParams``       was ``pltpu.TPUCompilerParams``
-  * ``jax.sharding.AxisType`` + the ``axis_types=`` kwarg of
-    ``jax.make_mesh``              did not exist (meshes were implicitly
-    all-auto, which is exactly what we want)
-  * ``jax.shard_map``              lived at
-    ``jax.experimental.shard_map.shard_map``
-
-Every kernel, the mesh launcher, the sharded backend, and the
-multi-device test snippets route through this module instead of probing
-``jax.__version__`` themselves.  Import-time failures here are the
-canary for a new drift — ``tests/test_compat.py`` asserts each shimmed
-symbol resolves under the installed JAX.
+Nothing here falls back quietly.  A backend that fails to initialise
+raises instead of reading as "no TPU", and a Pallas kernel asked for off
+the TPU raises unless ``REPRO_FORCE_PALLAS_INTERPRET=1`` selects the
+Pallas interpreter (the CPU test rig's override).
 """
 from __future__ import annotations
 
 import os
-from typing import Optional, Sequence
+from pathlib import Path
 
 import jax
 
-JAX_VERSION: tuple = tuple(int(x) for x in jax.__version__.split(".")[:3])
+INTERPRET_ENV = "REPRO_FORCE_PALLAS_INTERPRET"
 
-
-def tpu_compiler_params(*, dimension_semantics: Optional[tuple] = None, **kw):
-    """``pltpu.CompilerParams`` on new JAX, ``TPUCompilerParams`` on old.
-
-    Accepts the shared keyword surface (``dimension_semantics`` et al.)
-    and returns whichever dataclass the installed Pallas understands, so
-    ``pl.pallas_call(..., compiler_params=tpu_compiler_params(...))``
-    works on both sides of the rename.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = getattr(pltpu, "TPUCompilerParams", None)
-    if cls is None:  # pragma: no cover — ancient pallas: params were a dict
-        return dict(dimension_semantics=dimension_semantics, **kw)
-    if dimension_semantics is not None:
-        kw["dimension_semantics"] = tuple(dimension_semantics)
-    return cls(**kw)
-
-
-def make_auto_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
-                   *, devices=None):
-    """``jax.make_mesh`` with all-``Auto`` axis types on every version.
-
-    New JAX requires ``axis_types=(AxisType.Auto, ...)`` to opt out of
-    explicit sharding; old JAX predates ``AxisType`` and is implicitly
-    auto.  Both paths produce a mesh usable under ``with mesh:`` with
-    ``NamedSharding`` + ``PartitionSpec``.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kw = {}
-    if axis_type is not None:
-        kw["axis_types"] = (axis_type.Auto,) * len(axis_names)
-    if devices is not None:
-        kw["devices"] = devices
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kw)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep: bool = False):
-    """Version-stable ``shard_map`` (moved out of ``jax.experimental``).
-
-    The replication-check kwarg was spelled ``check_rep`` before the
-    ``check_vma`` rename, so try both spellings before dropping it —
-    callers pass ``check_rep=False`` because their bodies (scatter-add,
-    manual all_gather) fail the check, and silently re-enabling it
-    would error at trace time.
-    """
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        for kw in ({"check_vma": check_rep}, {"check_rep": check_rep}, {}):
-            try:
-                return fn(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-            except TypeError:
-                continue
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_rep)
-
-
-def cost_analysis(compiled) -> dict:
-    """Normalized ``compiled.cost_analysis()``: newer JAX returns one
-    dict, 0.4.x returns a per-computation list (possibly empty)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
+# fixed, so that every run of this checkout finds the same cache: the
+# path is part of the cache key
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
 def on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:  # pragma: no cover — backend init failure
-        return False
+    """Is the default device a TPU?  Backend initialisation errors
+    propagate."""
+    return jax.devices()[0].platform == "tpu"
 
 
 def force_interpret() -> bool:
-    """The one reader of the ``REPRO_FORCE_PALLAS_INTERPRET`` knob —
-    kernel dispatch (``kernels/ops.py``) and ``pallas_interpret`` both
-    route through here so the documented env var has one meaning."""
-    return os.environ.get("REPRO_FORCE_PALLAS_INTERPRET", "0") == "1"
+    """The one reader of the ``REPRO_FORCE_PALLAS_INTERPRET`` knob."""
+    return os.environ.get(INTERPRET_ENV, "0") == "1"
 
 
-def pallas_interpret(requested: Optional[bool] = None) -> bool:
-    """Resolve the ``interpret=`` flag for a ``pallas_call``.
-
-    Explicit requests win; otherwise fall back to interpret mode exactly
-    when no TPU is attached (CPU-only hosts run the same kernel through
-    the Pallas interpreter instead of erroring in Mosaic lowering).
-    """
-    if requested is not None:
-        return requested
+def pallas_interpret() -> bool:
+    """The ``interpret=`` flag for a ``pallas_call``: compiled (Mosaic)
+    on a TPU; off the TPU the Pallas interpreter, and only under the
+    interpret override — otherwise the request is an error."""
+    if on_tpu():
+        return False
     if force_interpret():
         return True
-    return not on_tpu()
+    raise RuntimeError(
+        f"a Pallas kernel was requested on {jax.devices()[0].platform!r}, "
+        f"not a TPU; set {INTERPRET_ENV}=1 to run it in the Pallas "
+        "interpreter")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory.  ``JAX_COMPILATION_CACHE_DIR``, when set, is
+    JAX's own setting and is left alone; otherwise the cache is
+    ``<checkout>/.jax_cache``.  Call from ``__main__`` code only — never
+    at import or from tests."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)

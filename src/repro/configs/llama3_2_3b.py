@@ -1,5 +1,5 @@
 """llama3.2-3b [dense] — small llama3. 28L d_model=3072 24H (GQA kv=8)
-d_ff=8192 vocab=128256 [hf:meta-llama/Llama-3.2-1B; unverified]."""
+d_ff=8192 vocab=128256 [hf:meta-llama/Llama-3.2-3B]."""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(
@@ -15,5 +15,5 @@ CONFIG = ModelConfig(
     rope_theta=5e5,
     tie_embeddings=True,
     group_size=1,
-    source="hf:meta-llama/Llama-3.2-1B; unverified",
+    source="hf:meta-llama/Llama-3.2-3B",
 )

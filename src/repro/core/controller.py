@@ -149,26 +149,9 @@ def charge_batch(state: dict, dom: jax.Array, amt: jax.Array, step,
     Zero-amount requests are gated only by freeze/throttle (a decode
     step that does not cross a page boundary allocates nothing but must
     still respect cgroup.freeze).
-
-    On TPU (or under ``REPRO_FORCE_PALLAS_INTERPRET=1``) the whole
-    batch runs in the fused Pallas enforcement kernel
-    (``kernels/enforcement.py``) — one pass over the control-state
-    table, ancestor walk resident in VMEM; the lax path below is the
-    CPU/interpret fallback and the kernel's conformance reference.
     """
     progs = as_programs(prog)
-    fused = _fused_charge_or_none()
-    if fused is not None:
-        return fused(state, dom.astype(jnp.int32), amt.astype(jnp.int32),
-                     step, progs)
-    return _lax_charge_batch(state, dom, amt, step, progs)
 
-
-def _lax_charge_batch(state: dict, dom: jax.Array, amt: jax.Array, step,
-                      progs):
-    """The lax.scan reference body of ``charge_batch`` — callable
-    directly (bypassing the fused dispatch) so the roofline and the
-    overhead benchmark can compile both paths side by side."""
     def one(carry, req):
         usage, peak, throttle_until, params, mem_stall = carry
         d, a = req
@@ -244,44 +227,14 @@ def uncharge_batch(state: dict, dom: jax.Array, amt: jax.Array):
 
 def slot_gate(state: dict, slot_dom: jax.Array, step, prog=None) -> jax.Array:
     """May each slot advance this step?  Dispatches ``on_gate`` of the
-    slot's domain program (default: no frozen/throttled ancestor).  On
-    TPU / forced interpret the fused Pallas gate kernel takes the same
-    decision in one pass (``kernels/enforcement.py``)."""
+    slot's domain program (default: no frozen/throttled ancestor)."""
     progs = as_programs(prog)
-    fused = _fused_gate_or_none()
-    if fused is not None:
-        return fused(state, slot_dom.astype(jnp.int32), step, progs)
-    return _lax_slot_gate(state, slot_dom, step, progs)
 
-
-def _lax_slot_gate(state: dict, slot_dom: jax.Array, step, progs):
-    """The vmapped reference body of ``slot_gate`` (see
-    ``_lax_charge_batch``)."""
     def one(d):
         view = _chain_view(state, state["usage"], state["throttle_until"],
                            state["prog"], d)
         return (d >= 0) & gate_decision(progs, view, step)
     return jax.vmap(one)(slot_dom.astype(jnp.int32))
-
-
-def _fused_charge_or_none():
-    """Resolve the fused Pallas charge kernel, or None for the lax
-    fallback — python-time dispatch (a trace constant), mirroring
-    ``kernels/ops._resolve``: Pallas on real TPUs or under the
-    ``REPRO_FORCE_PALLAS_INTERPRET=1`` conformance override."""
-    from repro import compat
-    if not (compat.on_tpu() or compat.force_interpret()):
-        return None
-    from repro.kernels.enforcement import fused_charge_batch
-    return fused_charge_batch
-
-
-def _fused_gate_or_none():
-    from repro import compat
-    if not (compat.on_tpu() or compat.force_interpret()):
-        return None
-    from repro.kernels.enforcement import fused_slot_gate
-    return fused_slot_gate
 
 
 # -------------------------------------------------------------- host mirror

@@ -39,9 +39,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import controller as C
 from repro.core import domains as D
 from repro.core.cgroup import ChargeTicket, DomainSpec, parent_path
@@ -112,8 +111,9 @@ class ShardedDeviceView:
             outs = fn(st1, *ops1)
             return tuple(jax.tree.map(lambda x: x[None], o) for o in outs)
         in_specs, out_specs = self._shard_specs(1 + len(operands), n_out)
-        return compat.shard_map(local, mesh=self.mesh, in_specs=in_specs,
-                                out_specs=out_specs)(state, *operands)
+        return jax.shard_map(local, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs,
+                             check_vma=False)(state, *operands)
 
     # ------------------------------------------------------------ the ops
 
@@ -204,8 +204,9 @@ class ShardedTableBackend:
         if mesh is None:
             devs = jax.devices()
             n_shards = n_shards or len(devs)
-            mesh = compat.make_auto_mesh((n_shards,), ("shard",),
-                                         devices=devs[:n_shards])
+            mesh = jax.make_mesh((n_shards,), ("shard",),
+                                 axis_types=(AxisType.Auto,),
+                                 devices=devs[:n_shards])
         self.mesh = mesh
         self.n_shards = mesh.devices.size
         self.per_shard_domains = n_domains

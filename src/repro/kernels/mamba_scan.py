@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _kernel(x_ref, dt_ref, ldec_ref, b_ref, c_ref, y_ref, h_ref, *, c: int):
     z = pl.program_id(2)
@@ -98,7 +96,7 @@ def ssd_pallas(x, dt, A, B, C, D, *, chunk: int = 256, h0=None,
         out_specs=pl.BlockSpec((1, c, 1, dh), lambda i, h, z: (i, z, h, 0)),
         out_shape=jax.ShapeDtypeStruct((b, s, nh, dh), x.dtype),
         scratch_shapes=[pltpu.VMEM((dh, N), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="ssd_chunked_scan",

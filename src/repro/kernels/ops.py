@@ -4,37 +4,26 @@ Every op has three implementations:
   * ``naive``      — smallest oracle (tests only; O(S^2) memory etc.)
   * ``blockwise``  — pure-JAX production path (CPU smoke tests + dry-run
                      lowering; same math the Pallas kernel implements)
-  * ``pallas``     — TPU kernel (``pl.pallas_call`` + BlockSpec).  On CPU
-                     it runs in interpret mode when
-                     ``REPRO_FORCE_PALLAS_INTERPRET=1`` (kernel tests).
+  * ``pallas``     — TPU kernel (``pl.pallas_call`` + BlockSpec).  Off
+                     the TPU it runs in the Pallas interpreter only under
+                     ``REPRO_FORCE_PALLAS_INTERPRET=1`` (kernel tests);
+                     without it an explicit ``impl="pallas"`` raises.
 
-``impl=None`` resolves to pallas on TPU, blockwise elsewhere.
+``impl=None`` resolves to pallas on TPU (or under the override),
+blockwise elsewhere.
 """
 from __future__ import annotations
 
 from typing import Optional
 
-import jax
-import jax.numpy as jnp
-
 from repro import compat
 from repro.kernels import ref
 
 
-def _on_tpu() -> bool:
-    return compat.on_tpu()
-
-
-def _interpret() -> bool:
-    return compat.force_interpret()
-
-
 def _resolve(impl: Optional[str]) -> str:
     if impl in (None, "auto"):
-        return "pallas" if (_on_tpu() or _interpret()) else "blockwise"
-    if impl == "pallas" and not (_on_tpu() or _interpret()):
-        # pallas requested but no TPU and no interpreter override: fall back
-        return "blockwise"
+        return ("pallas" if (compat.on_tpu() or compat.force_interpret())
+                else "blockwise")
     return impl
 
 
@@ -55,7 +44,8 @@ def flash_attention(q, k, v, *, causal: bool = True, scale=None,
         from repro.kernels.flash_attention import flash_attention_pallas
         return flash_attention_pallas(
             q, k, v, causal=causal, scale=scale,
-            block_q=block_q, block_k=block_k, interpret=not _on_tpu())
+            block_q=block_q, block_k=block_k,
+            interpret=compat.pallas_interpret())
     raise ValueError(f"unknown attention impl {impl!r}")
 
 
@@ -68,7 +58,8 @@ def decode_attention(q, k_cache, v_cache, lengths, *, scale=None,
     if impl == "pallas":
         from repro.kernels.decode_attention import decode_attention_pallas
         return decode_attention_pallas(q, k_cache, v_cache, lengths,
-                                       scale=scale, interpret=not _on_tpu())
+                                       scale=scale,
+                                       interpret=compat.pallas_interpret())
     raise ValueError(f"unknown decode impl {impl!r}")
 
 
@@ -83,7 +74,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
         from repro.kernels.decode_attention import paged_decode_attention_pallas
         return paged_decode_attention_pallas(
             q, k_pages, v_pages, page_table, lengths, scale=scale,
-            interpret=not _on_tpu())
+            interpret=compat.pallas_interpret())
     raise ValueError(f"unknown paged decode impl {impl!r}")
 
 
@@ -100,7 +91,7 @@ def ssd(x, dt, A, B, C, D, *, chunk: int = 256, h0=None,
     if impl == "pallas":
         from repro.kernels.mamba_scan import ssd_pallas
         return ssd_pallas(x, dt, A, B, C, D, chunk=chunk, h0=h0,
-                          interpret=not _on_tpu())
+                          interpret=compat.pallas_interpret())
     raise ValueError(f"unknown ssd impl {impl!r}")
 
 

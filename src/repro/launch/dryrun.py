@@ -1,6 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: jax locks the device count on first init.
+# the production meshes are 512 fake host devices: pin this process and
+# the cell subprocesses it starts (they inherit the environment) to the
+# CPU, so none of them takes an attached TPU
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ both MUST precede any jax import: jax locks them on first init.
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
@@ -32,11 +36,11 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.analysis import hlo as hlo_mod
 from repro.analysis.roofline import roofline_from_costs
 from repro.configs import SHAPES, cell_applicability, get_config, ARCH_IDS
-from repro.launch.mesh import HW, POD_CHIPS, make_production_mesh, rules_for
+from repro.launch.mesh import (POD_CHIPS, PRODUCTION_KIND,
+                               make_production_mesh, peaks, rules_for)
 from repro.models import model as M
 from repro.models.schema import Leaf, shape_structs, tree_map_schema
 from repro.perf import DEFAULT_PERF, PerfConfig
@@ -131,7 +135,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     compiled = lowered.compile()
     t_compile = time.time() - t0
     ma = compiled.memory_analysis()
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     txt = compiled.as_text()
     parsed = hlo_mod.analyze(txt, pod_size=POD_CHIPS)
     per_dev_bytes = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
@@ -146,13 +150,15 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             "temp_bytes": ma.temp_size_in_bytes,
             "alias_bytes": ma.alias_size_in_bytes,
             "per_device_bytes": per_dev_bytes,
-            "fits_hbm": bool(per_dev_bytes <= HW["hbm_bytes"]),
+            "fits_hbm": bool(per_dev_bytes
+                             <= peaks(PRODUCTION_KIND)["hbm_bytes"]),
         },
         "cost_analysis": {"flops": ca.get("flops", 0.0),
                           "bytes": ca.get("bytes accessed", 0.0)},
         "hlo": parsed,
     })
-    rec["roofline"] = roofline_from_costs(cfg, shape, parsed, n_chips=n_chips)
+    rec["roofline"] = roofline_from_costs(cfg, shape, parsed, n_chips=n_chips,
+                                         device_kind=PRODUCTION_KIND)
     return rec
 
 

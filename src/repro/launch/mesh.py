@@ -17,19 +17,39 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+from jax.sharding import AxisType
 
-from repro import compat
 from repro.configs.base import ShapeConfig
 from repro.models.schema import RULES
 
-# TPU v5e-class hardware constants (per chip) for the roofline
-HW = {
-    "flops_bf16": 197e12,       # peak bf16 FLOP/s
-    "hbm_bw": 819e9,            # HBM bytes/s
-    "ici_bw": 50e9,             # per-link ICI bytes/s
-    "dcn_bw": 25e9,             # cross-pod bytes/s
-    "hbm_bytes": 16 * 2 ** 30,  # capacity
+# Per-chip peaks, keyed by ``jax.Device.device_kind``.  Source: Google
+# Cloud documentation, "TPU v5e" (bf16 FLOP/s, HBM capacity and
+# bandwidth, 1,600 Gbit/s inter-chip interconnect = 4 links x 50 GB/s).
+# ``dcn_bw`` (cross-pod) is this repo's planning assumption, not a
+# published figure.
+PEAKS = {
+    "TPU v5 lite": {
+        "flops_bf16": 197e12,       # peak bf16 FLOP/s
+        "hbm_bw": 819e9,            # HBM bytes/s
+        "ici_bw": 50e9,             # per-link ICI bytes/s
+        "dcn_bw": 25e9,             # cross-pod bytes/s (assumption)
+        "hbm_bytes": 16 * 2 ** 30,  # capacity
+    },
 }
+
+# the chip the production meshes below are made of
+PRODUCTION_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """Peak table row for one device kind; an unknown kind is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak table entry for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
 
 POD_CHIPS = 256                 # devices per pod (16 x 16)
 
@@ -37,7 +57,8 @@ POD_CHIPS = 256                 # devices per pod (16 x 16)
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_auto_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def _batch_axes(mesh) -> tuple:

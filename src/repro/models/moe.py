@@ -20,7 +20,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.models.schema import Leaf
 from repro.perf import PerfConfig, DEFAULT_PERF
@@ -230,10 +229,10 @@ def _a2a_dispatch(cfg: ModelConfig, p, x, *, capacity_factor: float,
         y = jnp.zeros((Tl, d), xl.dtype).at[tok].add(gathered)
         return y.reshape(xl.shape), aux
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(), w_up_spec, w_up_spec, w_dn_spec),
-        out_specs=out_specs, check_rep=False)
+        out_specs=out_specs, check_vma=False)
     y, aux = fn(x, p["router"], p["wg"], p["wu"], p["wd"])
     return y, aux
 

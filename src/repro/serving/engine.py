@@ -35,6 +35,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core import domains as D
@@ -97,8 +98,26 @@ def _gate_shape(gate, x):
     return gate.reshape((1, gate.shape[0]) + (1,) * (x.ndim - 2))
 
 
+def _slot_sharding(mesh) -> NamedSharding:
+    """Decode-state placement on a control mesh: every state leaf is
+    ``(n_groups, slots, ...)``, split over the mesh's axis by slot."""
+    return NamedSharding(mesh, P(None, mesh.axis_names[0]))
+
+
 def _make_step_fn(cfg: ModelConfig, perf: PerfConfig, ecfg: EngineConfig,
                   view: DeviceView):
+    decode = functools.partial(M.decode_step, cfg, perf=perf)
+    mesh = getattr(view, "mesh", None)
+    if mesh is not None:
+        # Pallas kernels are not partitioned automatically: on a control
+        # mesh each device decodes its own slots with the replicated
+        # weights (data parallel over the slot axis)
+        slots = P(mesh.axis_names[0])
+        state = _slot_sharding(mesh).spec
+        decode = jax.shard_map(decode, mesh=mesh,
+                               in_specs=(P(), state, slots, slots),
+                               out_specs=(slots, state), check_vma=False)
+
     @functools.partial(jax.jit, static_argnames=("mode",), donate_argnums=(1, 2))
     def step_fn(params, dstate, ctrl, tokens, lengths, dom, amt, host_gate,
                 step_no, key, *, mode: str):
@@ -123,14 +142,14 @@ def _make_step_fn(cfg: ModelConfig, perf: PerfConfig, ecfg: EngineConfig,
             gate = host_gate & (dom >= 0)
             ctrl = view.account(ctrl, jnp.where(gate, dom, -1), amt)
             granted, stalled = gate, (dom >= 0) & ~gate
-        logits, new_state = M.decode_step(cfg, params, dstate, tokens,
-                                          lengths, perf=perf)
+        logits, new_state = decode(params, dstate, tokens, lengths)
+        finite = jnp.isfinite(logits).all()
         nxt = sample(logits, key, temperature=ecfg.temperature)
         new_state = jax.tree.map(
             lambda n, o: jnp.where(_gate_shape(gate, n), n, o),
             new_state, dstate)
         nxt = jnp.where(gate, nxt, tokens)
-        return nxt, new_state, ctrl, granted, stalled
+        return nxt, new_state, ctrl, granted, stalled, finite
 
     return step_fn
 
@@ -146,6 +165,7 @@ class EngineMetrics:
     n_thaws: int = 0
     n_evictions: int = 0
     n_rebuilds: int = 0                  # poisoned-daemon backend rebuilds
+    nonfinite_logit_steps: int = 0       # steps whose logits held NaN/Inf
     steps: int = 0
 
 
@@ -160,6 +180,20 @@ class Engine:
         self.caches = SlotCaches(cfg, ecfg.max_slots, ecfg.s_max)
         self.accountant = PageAccountant(ecfg.page_tokens)
         be = self._make_inner()
+        mesh = getattr(be, "mesh", None)
+        if mesh is not None:
+            # one jitted step takes the weights, the decode state and the
+            # control state together, so all three live on the control
+            # mesh: the control state split by tenant, the decode state
+            # (KV cache) split by slot, the weights a full replica on
+            # every device
+            if ecfg.max_slots % mesh.devices.size:
+                raise ValueError(
+                    f"max_slots={ecfg.max_slots} does not split over the "
+                    f"{mesh.devices.size}-device control mesh")
+            self.params = jax.device_put(params, NamedSharding(mesh, P()))
+            self.caches.state = jax.device_put(self.caches.state,
+                                               _slot_sharding(mesh))
         if ecfg.backend == "async":
             # lifecycle off the hot path: mkdir/rmdir/write/freeze/thaw/
             # lease ops run on the daemon thread in FIFO epochs, applied
@@ -554,7 +588,7 @@ class Engine:
             dom[slot] = s.dom_idx
             amt[slot] = self.accountant.crossing(s.length)
         self.key, sub = jax.random.split(self.key)
-        nxt, self.caches.state, new_ctrl, granted, stalled = \
+        nxt, self.caches.state, new_ctrl, granted, stalled, finite = \
             self._step(self.params, self.caches.state, self._view.state,
                        jnp.asarray(tokens), jnp.asarray(lengths),
                        jnp.asarray(dom), jnp.asarray(amt),
@@ -564,6 +598,7 @@ class Engine:
         self._view.commit(new_ctrl)
         nxt = np.asarray(nxt)
         granted = np.asarray(granted)
+        self.metrics.nonfinite_logit_steps += int(not bool(finite))
         # throttle-trigger accounting (memcg_bpf_ops delay counter)
         tu = np.asarray(self._view.state["throttle_until"]).reshape(-1)
         self.metrics.throttle_triggers += int(np.sum(tu > self._prev_throttle))
@@ -670,4 +705,5 @@ class Engine:
             "overshoot_pages": self.metrics.overshoot_pages,
             "session_overshoot_pages": self.metrics.session_overshoot_pages,
             "peak_pool_pages": max(self.metrics.root_usage, default=0),
+            "nonfinite_logit_steps": self.metrics.nonfinite_logit_steps,
         }

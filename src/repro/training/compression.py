@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 
 def quantize_leaf(g, err):
     """Symmetric int8 quantization with error feedback.  Returns
@@ -64,6 +62,6 @@ def compressed_psum(x, mesh, axis: str = "data"):
         total = gathered.astype(jnp.int32).sum(axis=0)
         return (total.astype(jnp.float32) * scale).astype(xs.dtype)
 
-    return compat.shard_map(body, mesh=mesh, in_specs=P(*(None,) * x.ndim),
-                            out_specs=P(*(None,) * x.ndim),
-                            check_rep=False)(x)
+    return jax.shard_map(body, mesh=mesh, in_specs=P(*(None,) * x.ndim),
+                         out_specs=P(*(None,) * x.ndim),
+                         check_vma=False)(x)

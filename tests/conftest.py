@@ -8,7 +8,7 @@ from repro.models import model as M
 from repro.models.schema import init_params
 from repro.perf import DEFAULT_PERF, replace as perf_replace
 
-jax.config.update("jax_platform_name", "cpu")
+jax.config.update("jax_platforms", "cpu")
 
 TINY_PERF = perf_replace(DEFAULT_PERF, scan_chunk=32, remat="none",
                          block_q=64, block_k=64)

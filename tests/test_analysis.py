@@ -12,6 +12,7 @@ def _run(code: str) -> str:
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
@@ -23,8 +24,8 @@ def test_parser_matches_analytic_scan_flops():
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.analysis.hlo import analyze
-from repro.compat import make_auto_mesh
-mesh = make_auto_mesh((2, 4), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 D = 128
 def body(x, w):
     return jax.nn.relu(jnp.einsum("bd,df->bf", x, w)), None
@@ -49,12 +50,11 @@ def test_parser_matches_cost_analysis_no_scan():
     out = _run(r"""
 import jax, jax.numpy as jnp
 from repro.analysis.hlo import analyze
-from repro.compat import cost_analysis
 def f(a, b):
     return (a @ b).sum()
 a = jnp.ones((64, 128)); b = jnp.ones((128, 32))
 compiled = jax.jit(f).lower(a, b).compile()
-ca = cost_analysis(compiled)
+ca = compiled.cost_analysis()
 r = analyze(compiled.as_text())
 # dot flops identical when there is no while loop
 assert abs(r["flops"] - 2 * 64 * 128 * 32) < 1e3, r["flops"]
@@ -69,8 +69,8 @@ def test_collective_classification_dcn():
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.analysis.hlo import analyze
-from repro.compat import make_auto_mesh
-mesh = make_auto_mesh((2, 4), ("pod", "data"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("pod", "data"), axis_types=(AxisType.Auto,) * 2)
 x = jax.ShapeDtypeStruct((8, 64), jnp.float32,
                          sharding=NamedSharding(mesh, P(("pod", "data"), None)))
 def f(t):
@@ -92,7 +92,8 @@ def test_roofline_terms():
     cfg = get_config("llama3.2-3b")
     parsed = {"flops": 1e13, "bytes": 1e12, "coll_bytes_total": 5e10,
               "coll_dcn_bytes": 1e10}
-    r = roofline_from_costs(cfg, SHAPES["train_4k"], parsed, n_chips=256)
+    r = roofline_from_costs(cfg, SHAPES["train_4k"], parsed, n_chips=256,
+                            device_kind="TPU v5 lite")
     assert r["compute_s"] == 1e13 / 197e12
     assert r["memory_s"] == 1e12 / 819e9
     assert abs(r["collective_s"] - (4e10 / 50e9 + 1e10 / 25e9)) < 1e-9

@@ -62,6 +62,7 @@ def _run_driver(tmp_path, extra):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable, "-m", "repro.launch.train",
            "--arch", "llama3.2-3b", "--reduced", "--steps", "16",
            "--batch", "2", "--seq", "32", "--ckpt-every", "5",

@@ -1,55 +1,19 @@
-"""repro.compat under the *installed* JAX: every shimmed symbol must
-resolve and produce a usable object — this is the regression canary for
-the API drift that once broke 26 tests (pltpu.CompilerParams rename,
-jax.sharding.AxisType / make_mesh axis_types, jax.shard_map move,
-cost_analysis list-vs-dict)."""
+"""Platform resolution under the installed JAX: the direct mesh /
+shard_map APIs the call sites use, and the no-hidden-fallback rules of
+``repro.compat`` (strict Pallas interpret resolution, backend errors
+propagate, peak table keyed by device kind)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro import compat
-
-
-def test_version_tuple():
-    assert len(compat.JAX_VERSION) == 3
-    assert all(isinstance(x, int) for x in compat.JAX_VERSION)
-
-
-def test_tpu_compiler_params_resolves():
-    params = compat.tpu_compiler_params(
-        dimension_semantics=("parallel", "arbitrary"))
-    assert params is not None
-    # whichever class the installed pallas exposes, the kwarg landed
-    assert tuple(getattr(params, "dimension_semantics", ())) == \
-        ("parallel", "arbitrary") or isinstance(params, dict)
-
-
-def test_tpu_compiler_params_accepted_by_pallas_call():
-    """The shimmed params must pass through a real (interpret-mode)
-    pallas_call on the installed version."""
-    from jax.experimental import pallas as pl
-
-    def kernel(x_ref, o_ref):
-        o_ref[...] = x_ref[...] * 2.0
-
-    x = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
-    out = pl.pallas_call(
-        kernel,
-        grid=(1,),
-        in_specs=[pl.BlockSpec((8, 8), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((8, 8), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((8, 8), jnp.float32),
-        compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=("arbitrary",)),
-        interpret=True,
-    )(x)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(x) * 2.0)
+from repro.kernels import ops
 
 
 def test_make_auto_mesh_resolves():
-    mesh = compat.make_auto_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     assert mesh.axis_names == ("data",)
     sh = NamedSharding(mesh, P("data"))
     y = jax.device_put(jnp.zeros((4, 2)), sh)
@@ -57,23 +21,50 @@ def test_make_auto_mesh_resolves():
 
 
 def test_shard_map_resolves_and_runs():
-    mesh = compat.make_auto_mesh((1,), ("s",))
-    fn = compat.shard_map(lambda x: x + 1, mesh=mesh,
-                          in_specs=(P("s"),), out_specs=P("s"))
+    mesh = jax.make_mesh((1,), ("s",), axis_types=(AxisType.Auto,))
+    fn = jax.shard_map(lambda x: x + 1, mesh=mesh, in_specs=(P("s"),),
+                       out_specs=P("s"), check_vma=False)
     out = fn(jnp.zeros((1, 3)))
     np.testing.assert_allclose(np.asarray(out), np.ones((1, 3)))
 
 
-def test_cost_analysis_normalized_to_dict():
-    compiled = jax.jit(lambda a: (a @ a).sum()).lower(
-        jnp.ones((8, 8))).compile()
-    ca = compat.cost_analysis(compiled)
-    assert isinstance(ca, dict)
-    assert ca.get("flops", 0) > 0
+def test_pallas_interpret_resolution(monkeypatch):
+    """Off the TPU the interpreter runs only under the override; without
+    it a Pallas request is an error, never a quiet swap."""
+    assert not compat.on_tpu()
+    monkeypatch.setenv(compat.INTERPRET_ENV, "1")
+    assert compat.pallas_interpret() is True
+    monkeypatch.setenv(compat.INTERPRET_ENV, "0")
+    with pytest.raises(RuntimeError, match=compat.INTERPRET_ENV):
+        compat.pallas_interpret()
 
 
-def test_pallas_interpret_resolution():
-    assert compat.pallas_interpret(True) is True
-    assert compat.pallas_interpret(False) is False
-    if not compat.on_tpu():
-        assert compat.pallas_interpret(None) is True
+def test_explicit_pallas_impl_off_tpu_raises(monkeypatch):
+    """``impl="pallas"`` is honoured or refused: off the TPU without the
+    override it raises instead of running the blockwise path."""
+    monkeypatch.delenv(compat.INTERPRET_ENV, raising=False)
+    q = jnp.zeros((1, 2, 128), jnp.float32)
+    kv = jnp.zeros((1, 16, 1, 128), jnp.float32)
+    lengths = jnp.ones((1,), jnp.int32)
+    with pytest.raises(RuntimeError, match="not a TPU"):
+        ops.decode_attention(q, kv, kv, lengths, impl="pallas")
+    # the unpinned default still resolves to the blockwise path here
+    out = ops.decode_attention(q, kv, kv, lengths)
+    assert out.shape == (1, 2, 128)
+
+
+def test_on_tpu_propagates_backend_errors(monkeypatch):
+    def broken():
+        raise RuntimeError("backend failed to initialise")
+    monkeypatch.setattr(jax, "devices", broken)
+    with pytest.raises(RuntimeError, match="failed to initialise"):
+        compat.on_tpu()
+
+
+def test_peak_table_keyed_by_device_kind():
+    from repro.launch.mesh import PRODUCTION_KIND, peaks
+    row = peaks("TPU v5 lite")
+    assert row["flops_bf16"] == 197e12 and row["hbm_bw"] == 819e9
+    assert peaks(PRODUCTION_KIND) is row
+    with pytest.raises(KeyError, match="cpu"):
+        peaks("cpu")

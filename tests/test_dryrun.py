@@ -23,6 +23,7 @@ print("CELLJSON:" + json.dumps(out))
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
+    env["JAX_PLATFORMS"] = "cpu"
     # run_cell is imported from dryrun, whose first lines set XLA_FLAGS
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=1200)

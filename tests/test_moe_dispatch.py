@@ -68,8 +68,8 @@ from repro.models.schema import init_params, shardings
 from repro.perf import DEFAULT_PERF, replace as perf_replace
 from repro.sharding_ctx import activation_rules
 
-from repro.compat import make_auto_mesh
-mesh = make_auto_mesh((2, 4), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 rules = {"tp": "model", "fsdp": "data", "ep": "model", "ep2": "data",
          "act_batch": "data", "act_seq": "model", "layers": None}
 cfg = dataclasses.replace(reduced(get_config("jamba-v0.1-52b")),
@@ -102,6 +102,7 @@ print("A2A OK")
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0 and "A2A OK" in out.stdout, out.stderr[-3000:]

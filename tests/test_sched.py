@@ -346,6 +346,7 @@ def test_sched_parity_on_8_fake_devices():
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(root, "src"), root])
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", _SCHED_8DEV], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0 and "SCHED8 OK" in out.stdout, \

@@ -129,8 +129,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.training.compression import compressed_psum
-from repro.compat import make_auto_mesh
-mesh = make_auto_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 x = jnp.linspace(-1.0, 1.0, 64).reshape(8, 8)
 with mesh:
     got = jax.jit(lambda t: compressed_psum(t, mesh, "data"))(x)
@@ -144,6 +144,7 @@ print("OK")
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=300)
     assert out.returncode == 0 and "OK" in out.stdout, out.stderr[-2000:]
