@@ -62,6 +62,7 @@ from repro.core.progs import (ChainView, PolicyProgram, Request, as_program,
                               as_programs, charge_decision, check_registry,
                               pad_row, path_in_scope,
                               registry_unknown_params, registry_width)
+from repro.spans import OFF, Tracer, to_host
 
 UNLIMITED = D.UNLIMITED
 
@@ -691,7 +692,7 @@ class DeviceTableBackend:
 
         from repro.core.sched import flat_weights_by_path
         st = self.table.state
-        w = np.asarray(st["weight"])
+        w = to_host(st["weight"])
         flat = flat_weights_by_path(
             {p: int(w[i]) for p, i in self.table.index.items()})
         arr = np.zeros((self.table.n,), np.float32)
@@ -748,9 +749,9 @@ class DeviceTableBackend:
             self.table.state, jnp.array([idx], jnp.int32),
             jnp.array([pages], jnp.int32), step, self.table.progs)
         self.table.state = st
-        window = max(0, int(st["throttle_until"][idx]) - step)
-        return ChargeTicket(granted=bool(granted[0]),
-                            stalled=bool(stalled[0]),
+        window = max(0, int(to_host(st["throttle_until"][idx])) - step)
+        return ChargeTicket(granted=bool(to_host(granted)[0]),
+                            stalled=bool(to_host(stalled)[0]),
                             delay_ms=window * self.table.prog.step_ms)
 
     def uncharge(self, path: str, pages: int) -> None:
@@ -778,7 +779,7 @@ class DeviceTableBackend:
         st, advance = jit_schedule(self.table.progs, self.table.state,
                                    dom, cost, int(step), int(budget))
         self.table.state = st
-        return [bool(a) for a in np.asarray(advance)]
+        return [bool(a) for a in to_host(advance)]
 
     # subtree control
     def _subtree(self, path: str) -> list[str]:
@@ -828,16 +829,17 @@ class DeviceTableBackend:
             st = self.table.state
             idx = self.table.index[path]
             return {"high": 0, "max": 0,
-                    "throttle": int(int(st["throttle_until"][idx]) > 0),
+                    "throttle": int(int(to_host(st["throttle_until"][idx]))
+                                    > 0),
                     "oom_kill": 0}
         if file in P.STALL_FILES:
             key = "mem_stall" if file == "memory.stall" else "cpu_stall"
-            col = np.asarray(self.table.state[key])
+            col = to_host(self.table.state[key])
             return P.subtree_counts_by_path(
                 {p: int(col[i]) for p, i in self.table.index.items()
                  if path_in_scope(path, p)})[path]
         idx = self.table.index[path]
-        return int(self.table.state[self._FILE_KEY[file]][idx])
+        return int(to_host(self.table.state[self._FILE_KEY[file]][idx]))
 
     def write(self, path: str, file: str, value) -> None:
         if file == "cgroup.freeze":
@@ -858,27 +860,27 @@ class DeviceTableBackend:
         st = self.table.state
         return {"paths": list(self.table.index),
                 "index": dict(self.table.index),
-                "usage": np.asarray(st["usage"]),
-                "high": np.asarray(st["high"]),
-                "max": np.asarray(st["max"]),
-                "parent": np.asarray(st["parent"]),
-                "active": np.asarray(st["active"]),
-                "peak": np.asarray(st["peak"]),
-                "low": np.asarray(st["low"]),
-                "priority": np.asarray(st["priority"]),
-                "frozen": np.asarray(st["frozen"]),
-                "throttle_until": np.asarray(st["throttle_until"]),
-                "params": np.asarray(st["prog"]),
-                "weight": np.asarray(st["weight"]),
-                "cpu_max": np.asarray(st["cpu_max"]),
-                "flat_weight": np.asarray(st["flat_weight"]),
-                "vruntime": np.asarray(st["vruntime"]),
-                "cpu_used": np.asarray(st["cpu_used"]),
-                "cpu_stamp": np.asarray(st["cpu_stamp"]),
-                "mem_stall": np.asarray(st["mem_stall"]),
-                "cpu_stall": np.asarray(st["cpu_stall"]),
-                "prog_id": np.asarray(st["prog_id"]),
-                "root_usage": int(st["usage"][0])}
+                "usage": to_host(st["usage"]),
+                "high": to_host(st["high"]),
+                "max": to_host(st["max"]),
+                "parent": to_host(st["parent"]),
+                "active": to_host(st["active"]),
+                "peak": to_host(st["peak"]),
+                "low": to_host(st["low"]),
+                "priority": to_host(st["priority"]),
+                "frozen": to_host(st["frozen"]),
+                "throttle_until": to_host(st["throttle_until"]),
+                "params": to_host(st["prog"]),
+                "weight": to_host(st["weight"]),
+                "cpu_max": to_host(st["cpu_max"]),
+                "flat_weight": to_host(st["flat_weight"]),
+                "vruntime": to_host(st["vruntime"]),
+                "cpu_used": to_host(st["cpu_used"]),
+                "cpu_stamp": to_host(st["cpu_stamp"]),
+                "mem_stall": to_host(st["mem_stall"]),
+                "cpu_stall": to_host(st["cpu_stall"]),
+                "prog_id": to_host(st["prog_id"]),
+                "root_usage": int(to_host(st["usage"][0]))}
 
     def restore(self, snap: dict) -> None:
         """Rebuild index + device state from a ``snapshot()`` dict —
@@ -972,10 +974,11 @@ class Lease:
         cg = self.channel.cg
         if not cg.exists(self.path):
             return 0
-        if not self.killed:
-            cg.log.emit(cg.now, Ev.DONE, self.path,
-                        peak=cg.read(self.path, "memory.peak"))
-        return cg.rmdir(self.path, transfer_residual=transfer_residual)
+        with cg.tracer.span("lease.close"):
+            if not self.killed:
+                cg.log.emit(cg.now, Ev.DONE, self.path,
+                            peak=cg.read(self.path, "memory.peak"))
+            return cg.rmdir(self.path, transfer_residual=transfer_residual)
 
 
 class IntentChannel:
@@ -1005,7 +1008,9 @@ class IntentChannel:
         if high is None:
             high = hint_to_high(hint)
         path = f"{parent.rstrip('/')}/{tool_id}"
-        self.cg.mkdir(path, DomainSpec(high=high, max=max, priority=priority))
+        with self.cg.tracer.span("lease.open"):
+            self.cg.mkdir(path, DomainSpec(high=high, max=max,
+                                           priority=priority))
         self.n_declared += 1
         lease = Lease(self, tool_id, path, parent, hint, high,
                       priority=priority, max=max, attempt=attempt)
@@ -1089,10 +1094,14 @@ class IntentChannel:
 
 class AgentCgroup:
     """The unified control plane: cgroupfs-style files + intent channel
-    over a pluggable enforcement backend."""
+    over a pluggable enforcement backend.
 
-    def __init__(self, backend: Backend):
+    Every backend meets here, so the spans of ``tracer``
+    (``repro/spans.py``; off by default) are the same on all of them."""
+
+    def __init__(self, backend: Backend, tracer: Optional[Tracer] = None):
         self.backend = backend
+        self.tracer = tracer if tracer is not None else OFF
         self.intent = IntentChannel(self)
         self._now = 0.0
         # PSI averaging over the backends' raw stall counters; decay
@@ -1110,14 +1119,16 @@ class AgentCgroup:
         parent = parent_path(path)
         if not self.backend.exists(parent):
             raise FileNotFoundError(f"parent {parent!r} of {path!r}")
-        return self.backend.mkdir(path, spec)
+        with self.tracer.span("cg.mkdir"):
+            return self.backend.mkdir(path, spec)
 
     def rmdir(self, path: str, *, transfer_residual: bool = True) -> int:
         """Remove a leaf domain.  By default residual charges transfer
         to the parent (pages outliving the tool call stay accounted to
         the session); with ``transfer_residual=False`` they release."""
-        self._pressure.forget(path)
-        return self.backend.rmdir(path, transfer_residual)
+        with self.tracer.span("cg.rmdir"):
+            self._pressure.forget(path)
+            return self.backend.rmdir(path, transfer_residual)
 
     def exists(self, path: str) -> bool:
         return self.backend.exists(path)
@@ -1181,7 +1192,8 @@ class AgentCgroup:
 
     def write(self, path: str, file: str, value) -> None:
         assert file in _WRITE_FILES, file
-        self.backend.write(path, file, value)
+        with self.tracer.span("cg.write"):
+            self.backend.write(path, file, value)
 
     def pressure_clock(self, *, step_quantum: Optional[float] = None,
                        windows: Optional[tuple] = None) -> None:
@@ -1208,16 +1220,18 @@ class AgentCgroup:
         return self.backend.try_charge(path, pages, step)
 
     def uncharge(self, path: Union[str, int], pages: int) -> None:
-        if isinstance(path, int):
-            path = self.path_of(path)
-        self.backend.uncharge(path, pages)
+        with self.tracer.span("cg.uncharge"):
+            if isinstance(path, int):
+                path = self.path_of(path)
+            self.backend.uncharge(path, pages)
 
     def charge_unchecked(self, path: Union[str, int], pages: int) -> None:
         """Lifecycle bookkeeping charge (residual transfer, thaw
         re-charge): the pages are already resident, never denied."""
-        if isinstance(path, int):
-            path = self.path_of(path)
-        self.backend.charge_unchecked(path, pages)
+        with self.tracer.span("cg.charge_unchecked"):
+            if isinstance(path, int):
+                path = self.path_of(path)
+            self.backend.charge_unchecked(path, pages)
 
     # ------------------------------------------------------------ scheduling
 
@@ -1237,10 +1251,12 @@ class AgentCgroup:
     # ------------------------------------------------------ subtree control
 
     def freeze(self, path: str) -> None:
-        self.backend.freeze(path)
+        with self.tracer.span("cg.freeze"):
+            self.backend.freeze(path)
 
     def thaw(self, path: str) -> None:
-        self.backend.thaw(path)
+        with self.tracer.span("cg.thaw"):
+            self.backend.thaw(path)
 
     def kill(self, path: str) -> int:
         """memory.oom.group analogue.  Any open lease inside the killed
@@ -1280,7 +1296,8 @@ class AgentCgroup:
         addressable by ``handle()`` (the slot index); for
         backend-agnostic lookup use ``snapshot()['index'][path]``.
         """
-        return self.backend.snapshot()
+        with self.tracer.span("cg.snapshot"):
+            return self.backend.snapshot()
 
     def restore(self, snap: dict) -> None:
         """Rebuild backend control state from a ``snapshot()`` dict —
@@ -1307,7 +1324,10 @@ class AgentCgroup:
         backends return the epoch now reflected); a no-op on
         synchronous backends."""
         fn = getattr(self.backend, "flush", None)
-        return fn() if fn is not None else None
+        if fn is None:
+            return None
+        with self.tracer.span("cg.flush"):
+            return fn()
 
     @property
     def log(self) -> EventLog:

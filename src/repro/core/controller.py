@@ -45,6 +45,7 @@ from repro.core.progs import (ChainView, PolicyProgram, Request, as_program,
                               as_programs, charge_decision, check_registry,
                               gate_decision, pad_row, path_in_scope,
                               registry_unknown_params, registry_width)
+from repro.spans import to_host
 
 UNLIMITED = D.UNLIMITED
 DEPTH = 4          # root / tenant / session / tool-call
@@ -202,8 +203,8 @@ def host_charge(state: dict, idx: int, amt: int) -> dict:
     """Unconditional hierarchical charge for host-side lifecycle moves
     (residual transfer on tool-domain close, thaw re-charge).  Never
     denied — the pages are already resident; this is bookkeeping."""
-    usage = np.asarray(state["usage"]).copy()
-    parent = np.asarray(state["parent"])
+    usage = to_host(state["usage"]).copy()
+    parent = to_host(state["parent"])
     i = idx
     for _ in range(DEPTH):
         if i < 0:
@@ -303,11 +304,11 @@ class DeviceDomainTable:
             self.scopes.append(scope)
         check_registry(self.progs)
         width = registry_width(self.progs)
-        old = np.asarray(self.state["prog"])
+        old = to_host(self.state["prog"])
         rows = np.zeros((self.n, width), np.float32)
         keep = min(width, old.shape[1])
         rows[:, :keep] = old[:, :keep]
-        ids = np.asarray(self.state["prog_id"]).copy()
+        ids = to_host(self.state["prog_id"]).copy()
         for path, idx in self.index.items():
             if path_in_scope(scope, path):
                 ids[idx] = k
@@ -325,7 +326,7 @@ class DeviceDomainTable:
             raise KeyError(
                 f"no registered program has param(s) {sorted(unknown)}; "
                 f"knobs: {sorted(set().union(*(p.param_names for p in self.progs)))}")
-        ids = np.asarray(self.state["prog_id"])
+        ids = to_host(self.state["prog_id"])
         prog = self.state["prog"]
         for p in paths:
             idx = self.index[p]
@@ -340,10 +341,10 @@ class DeviceDomainTable:
         propagate down) — and, with ``_fresh_prog_id``, the parent's
         program slot: a child created after a subtree attach runs the
         subtree's program, not the root default."""
-        return np.asarray(self.state["prog"][pidx])
+        return to_host(self.state["prog"][pidx])
 
     def _fresh_prog_id(self, pidx: int) -> int:
-        return int(self.state["prog_id"][pidx])
+        return int(to_host(self.state["prog_id"][pidx]))
 
     # ------------------------------------------------------------ lifecycle
 
@@ -385,7 +386,7 @@ class DeviceDomainTable:
 
     def remove(self, path: str) -> None:
         idx = self.index.pop(path)
-        residual = int(self.state["usage"][idx])
+        residual = int(to_host(self.state["usage"][idx]))
         if residual:
             # release residual charges up the chain (host-side lifecycle op)
             self.state = uncharge_batch(self.state,
@@ -412,7 +413,7 @@ class DeviceDomainTable:
         self.state = dict(st, frozen=st["frozen"].at[idx].set(flag))
 
     def usage(self, path: str) -> int:
-        return int(self.state["usage"][self.index[path]])
+        return int(to_host(self.state["usage"][self.index[path]]))
 
     def peak(self, path: str) -> int:
-        return int(self.state["peak"][self.index[path]])
+        return int(to_host(self.state["peak"][self.index[path]]))

@@ -49,6 +49,7 @@ from repro.core.pressure import saturating_count
 from repro.core.progs import (PolicyProgram, as_program, as_programs,
                               check_registry, pad_row, path_in_scope,
                               registry_unknown_params, registry_width)
+from repro.spans import to_host
 
 UNLIMITED = D.UNLIMITED
 
@@ -266,11 +267,11 @@ class ShardedTableBackend:
             self.scopes.append(scope)
         check_registry(self.progs)
         width = registry_width(self.progs)
-        old = np.asarray(self.state["prog"])
+        old = to_host(self.state["prog"])
         rows = np.zeros((S, n, width), np.float32)
         keep = min(width, old.shape[2])
         rows[:, :, :keep] = old[:, :, :keep]
-        ids = np.asarray(self.state["prog_id"]).copy()
+        ids = to_host(self.state["prog_id"]).copy()
         for path, (s, i) in self.index.items():
             if path_in_scope(scope, path):
                 ids[s, i] = k
@@ -285,7 +286,7 @@ class ShardedTableBackend:
             raise KeyError(
                 f"no registered program has param(s) {sorted(unknown)}; "
                 f"knobs: {sorted(set().union(*(p.param_names for p in self.progs)))}")
-        ids = np.asarray(self.state["prog_id"])
+        ids = to_host(self.state["prog_id"])
         prog = self.state["prog"]
         for p in self._subtree(path):
             s, i = self.index[p]
@@ -307,7 +308,7 @@ class ShardedTableBackend:
         sees a slice of the tree.  Every shard's local root mirrors the
         global root (flat 1.0)."""
         from repro.core.sched import flat_weights_by_path
-        w = np.asarray(self.state["weight"])
+        w = to_host(self.state["weight"])
         flat = flat_weights_by_path(
             {p: int(w[s, i]) for p, (s, i) in self.index.items()})
         arr = np.zeros((self.n_shards, self.per_shard_domains), np.float32)
@@ -381,8 +382,8 @@ class ShardedTableBackend:
         }
         # children inherit their parent's live row AND program slot, so a
         # domain created after a subtree attach runs the subtree's program
-        row = np.asarray(st["prog"][shard, pidx])
-        pid = int(st["prog_id"][shard, pidx])
+        row = to_host(st["prog"][shard, pidx])
+        pid = int(to_host(st["prog_id"][shard, pidx]))
         self.state = dict(st, **{
             k: st[k].at[shard, idx].set(v) for k, v in upd.items()},
             prog=st["prog"].at[shard, idx].set(jnp.asarray(row)),
@@ -394,7 +395,7 @@ class ShardedTableBackend:
 
     def rmdir(self, path: str, transfer_residual: bool) -> int:
         shard, idx = self.index[path]
-        residual = int(self.state["usage"][shard, idx])
+        residual = int(to_host(self.state["usage"][shard, idx]))
         parent = parent_path(path)
         if residual:
             sub = self._slice(shard)
@@ -494,7 +495,7 @@ class ShardedTableBackend:
             self.state, jnp.int32(shard), jnp.int32(idx), jnp.int32(pages),
             jnp.int32(step))
         granted, stalled, root_ok, window = (int(x) for x in
-                                             np.asarray(flags))
+                                             to_host(flags))
         self.state = state
         if not root_ok:
             return ChargeTicket(granted=False, stalled=True, blocked_by="/")
@@ -526,7 +527,7 @@ class ShardedTableBackend:
         shard with a per-shard budget — the per-device-group
         convention."""
         from repro.core.sched import jit_schedule
-        st = {k: np.asarray(v) for k, v in self.state.items()}
+        st = {k: to_host(v) for k, v in self.state.items()}
         S, n = self.n_shards, self.per_shard_domains
         base = (np.arange(S) * n)[:, None]
         parent = np.where(st["parent"] >= 0, st["parent"] + base, -1)
@@ -545,9 +546,9 @@ class ShardedTableBackend:
         sh = NamedSharding(self.mesh, P("shard"))
         self.state = dict(self.state, **{
             k: jax.device_put(
-                jnp.asarray(np.asarray(new[k]).reshape(S, n)), sh)
+                jnp.asarray(to_host(new[k]).reshape(S, n)), sh)
             for k in ("vruntime", "cpu_used", "cpu_stamp", "cpu_stall")})
-        return [bool(a) for a in np.asarray(advance)]
+        return [bool(a) for a in to_host(advance)]
 
     # ------------------------------------------------------ subtree control
 
@@ -578,7 +579,7 @@ class ShardedTableBackend:
         usage released from the owning shard's chain, every node retired
         in place (still registered, denying charges via frozen)."""
         shard, idx = self.index[path]
-        freed = int(self.state["usage"][shard, idx])
+        freed = int(to_host(self.state["usage"][shard, idx]))
         if freed:
             self.uncharge(path, freed)
         st = self.state
@@ -614,9 +615,10 @@ class ShardedTableBackend:
         for s in range(self.n_shards):
             if hook is not None:
                 hook(s)
-            usage += int(self.state["usage"][s, 0])
-            peak += int(self.state["peak"][s, 0])
-            throttled |= bool(self.state["throttle_until"][s, 0] > 0)
+            usage += int(to_host(self.state["usage"][s, 0]))
+            peak += int(to_host(self.state["peak"][s, 0]))
+            tu = to_host(self.state["throttle_until"][s, 0])
+            throttled |= bool(tu > 0)
         return {"usage": usage, "peak": peak, "throttled": throttled}
 
     def read(self, path: str, file: str):
@@ -626,7 +628,7 @@ class ShardedTableBackend:
             # host-side over the logical path tree, gathering each
             # registered path's row from its owning shard
             key = "mem_stall" if file == "memory.stall" else "cpu_stall"
-            col = np.asarray(self.state[key])
+            col = to_host(self.state[key])
             return PSI.subtree_counts_by_path(
                 {p: int(col[s, i]) for p, (s, i) in self.index.items()
                  if path_in_scope(path, p)})[path]
@@ -641,13 +643,13 @@ class ShardedTableBackend:
                 return {"high": 0, "max": 0,
                         "throttle": int(self.reconcile()["throttled"]),
                         "oom_kill": 0}
-            return int(self.state[self._FILE_KEY[file]][0, 0])
+            return int(to_host(self.state[self._FILE_KEY[file]][0, 0]))
         shard, idx = self.index[path]
         if file == "memory.events":
-            tu = int(self.state["throttle_until"][shard, idx])
+            tu = int(to_host(self.state["throttle_until"][shard, idx]))
             return {"high": 0, "max": 0, "throttle": int(tu > 0),
                     "oom_kill": 0}
-        return int(self.state[self._FILE_KEY[file]][shard, idx])
+        return int(to_host(self.state[self._FILE_KEY[file]][shard, idx]))
 
     def write(self, path: str, file: str, value) -> None:
         if file == "cgroup.freeze":
@@ -676,7 +678,7 @@ class ShardedTableBackend:
         """One host sync; rows addressable by global handle
         (``shard * n_domains + local``), parent pointers rebased to
         global handles, plus the reconciled root usage."""
-        st = {k: np.asarray(v) for k, v in self.state.items()}
+        st = {k: to_host(v) for k, v in self.state.items()}
         S, n = self.n_shards, self.per_shard_domains
         base = (np.arange(S) * n)[:, None]
         parent = st["parent"]

@@ -29,7 +29,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Optional
 
 import jax
@@ -45,7 +45,6 @@ from repro.core.cgroup import (AgentCgroup, DeviceTableBackend, DeviceView,
                                DomainSpec)
 from repro.core.controller import ControllerConfig
 from repro.core.daemon import AsyncDaemonBackend, DaemonError
-from repro.core.events import Ev, EventLog
 from repro.core.intent import Hint
 from repro.core.progs import PolicyProgram
 from repro.models import model as M
@@ -53,6 +52,7 @@ from repro.perf import PerfConfig, DEFAULT_PERF
 from repro.serving.kvcache import PageAccountant, SlotCaches
 from repro.serving.sampling import sample
 from repro.serving.session import Phase, Session, SState
+from repro.spans import OFF, Tracer, to_host
 
 
 @dataclass(frozen=True)
@@ -121,6 +121,9 @@ def _make_step_fn(cfg: ModelConfig, perf: PerfConfig, ecfg: EngineConfig,
     @functools.partial(jax.jit, static_argnames=("mode",), donate_argnums=(1, 2))
     def step_fn(params, dstate, ctrl, tokens, lengths, dom, amt, host_gate,
                 step_no, key, *, mode: str):
+        # the named scopes label the compiled step's operations (HLO
+        # metadata only), so a profiler trace can be split into
+        # enforcement and model work
         if ecfg.sched_slots is not None:
             # weighted step scheduler: rank this step's runnable slots by
             # vruntime and grant at most sched_slots of them; a slot the
@@ -128,27 +131,32 @@ def _make_step_fn(cfg: ModelConfig, perf: PerfConfig, ecfg: EngineConfig,
             # charge never reaches the memory controller).  Slots whose
             # program weight is <= 0 bypass the budget entirely, so the
             # stock program keeps this a no-op.
-            cost = (dom >= 0).astype(jnp.int32)
-            ctrl, advance = view.schedule(ctrl, dom, cost, step_no,
-                                          ecfg.sched_slots)
-            dom = jnp.where(advance, dom, -1)
-        if mode == "inkernel":
-            # in-step enforcement: charge + gate inside the same program
-            ctrl, granted, stalled = view.charge(ctrl, dom, amt, step_no)
-            gate = granted
-        else:
-            # user-space baseline: the (stale) host gate decides; usage is
-            # charged after the fact, so bursts overshoot the budget
-            gate = host_gate & (dom >= 0)
-            ctrl = view.account(ctrl, jnp.where(gate, dom, -1), amt)
-            granted, stalled = gate, (dom >= 0) & ~gate
-        logits, new_state = decode(params, dstate, tokens, lengths)
-        finite = jnp.isfinite(logits).all()
-        nxt = sample(logits, key, temperature=ecfg.temperature)
-        new_state = jax.tree.map(
-            lambda n, o: jnp.where(_gate_shape(gate, n), n, o),
-            new_state, dstate)
-        nxt = jnp.where(gate, nxt, tokens)
+            with jax.named_scope("agentcgroup.schedule"):
+                cost = (dom >= 0).astype(jnp.int32)
+                ctrl, advance = view.schedule(ctrl, dom, cost, step_no,
+                                              ecfg.sched_slots)
+                dom = jnp.where(advance, dom, -1)
+        with jax.named_scope("agentcgroup.charge"):
+            if mode == "inkernel":
+                # in-step enforcement: charge + gate inside the same program
+                ctrl, granted, stalled = view.charge(ctrl, dom, amt, step_no)
+                gate = granted
+            else:
+                # user-space baseline: the (stale) host gate decides; usage
+                # is charged after the fact, so bursts overshoot the budget
+                gate = host_gate & (dom >= 0)
+                ctrl = view.account(ctrl, jnp.where(gate, dom, -1), amt)
+                granted, stalled = gate, (dom >= 0) & ~gate
+        with jax.named_scope("model.decode"):
+            logits, new_state = decode(params, dstate, tokens, lengths)
+        with jax.named_scope("model.sample"):
+            finite = jnp.isfinite(logits).all()
+            nxt = sample(logits, key, temperature=ecfg.temperature)
+        with jax.named_scope("agentcgroup.gate"):
+            new_state = jax.tree.map(
+                lambda n, o: jnp.where(_gate_shape(gate, n), n, o),
+                new_state, dstate)
+            nxt = jnp.where(gate, nxt, tokens)
         return nxt, new_state, ctrl, granted, stalled, finite
 
     return step_fn
@@ -156,7 +164,7 @@ def _make_step_fn(cfg: ModelConfig, perf: PerfConfig, ecfg: EngineConfig,
 
 @dataclass
 class EngineMetrics:
-    root_usage: list = field(default_factory=list)
+    peak_pool_pages: int = 0             # max root usage at a step boundary
     overshoot_pages: int = 0             # max pages over pool budget
     session_overshoot_pages: int = 0     # max pages over any session high
     throttle_triggers: int = 0
@@ -172,12 +180,17 @@ class EngineMetrics:
 class Engine:
     def __init__(self, cfg: ModelConfig, params, *,
                  perf: PerfConfig = DEFAULT_PERF,
-                 ecfg: EngineConfig = EngineConfig(), seed: int = 0):
+                 ecfg: EngineConfig = EngineConfig(), seed: int = 0,
+                 tracer: Optional[Tracer] = None):
         self.cfg = cfg
         self.params = params
         self.perf = perf
         self.ecfg = ecfg
-        self.caches = SlotCaches(cfg, ecfg.max_slots, ecfg.s_max)
+        # spans and counters of the served path (repro/spans.py); off by
+        # default
+        self.tracer = tracer if tracer is not None else OFF
+        self.caches = SlotCaches(cfg, ecfg.max_slots, ecfg.s_max,
+                                 tracer=self.tracer)
         self.accountant = PageAccountant(ecfg.page_tokens)
         be = self._make_inner()
         mesh = getattr(be, "mesh", None)
@@ -201,7 +214,7 @@ class Engine:
             # closes over the INNER backend's device view and never
             # blocks on lifecycle work
             be = AsyncDaemonBackend(be)
-        self.cg = AgentCgroup(be)
+        self.cg = AgentCgroup(be, tracer=self.tracer)
         # the engine's facade clock counts steps (set_time(step_no)),
         # not ms: one step per clock unit, PSI windows converted from
         # ms to steps via the controller's step_ms
@@ -217,7 +230,6 @@ class Engine:
         # (root_usage sums every group) is pool_pages * n_shards
         self.pool_capacity = ecfg.pool_pages * getattr(be, "n_shards", 1)
         self._view = self.cg.device_view()
-        self.log = EventLog()
         self.metrics = EngineMetrics()
         self.sessions: dict[str, Session] = {}
         self.waiting: list[str] = []
@@ -281,6 +293,7 @@ class Engine:
         if not self.cg.exists(tenant_path):
             self.cg.mkdir(tenant_path)
         self.waiting.append(session.sid)
+        self.tracer.begin("session.queued", session.sid)
 
     def _try_admit(self) -> None:
         still = []
@@ -300,7 +313,7 @@ class Engine:
             s.t_admit = self.step_no
             self.slot_session[slot] = sid
             s.start()
-            self.log.emit(self.step_no, Ev.ADMIT, s.domain)
+            self.tracer.end("session.queued", sid)
         self.waiting = still
 
     # --------------------------------------------------- tool-call domains
@@ -325,12 +338,22 @@ class Engine:
                                            parent=s.domain,
                                            priority=s.priority, high=high)
             self._lease[s.sid] = lease
+            self.tracer.begin("session.tool_call", s.sid, {
+                "hint": hint.value if hint is not None else None,
+                "memory.high": high})
             s.dom_idx = self.cg.handle(lease.path)
         elif not in_burst and has:
             # context pages persist: lease close moves the residual
             # charge up to the session
-            self._lease.pop(s.sid).close()
+            self._close_lease(s)
             s.dom_idx = self.cg.handle(s.domain)
+
+    def _close_lease(self, s: Session) -> None:
+        """Close the session's open tool-call lease, if any: its residual
+        pages move to the session."""
+        if s.sid in self._lease:
+            self._lease.pop(s.sid).close()
+            self.tracer.end("session.tool_call", s.sid)
 
     # -------------------------------------------------------------- daemon
 
@@ -387,20 +410,23 @@ class Engine:
 
     def _daemon(self) -> None:
         e = self.ecfg
-        snap = self.cg.snapshot()
-        # last known-good step-boundary snapshot: the rebuild-from-
-        # snapshot path (poisoned async daemon) restores from here
-        self._last_snapshot = snap
-        root_usage = int(snap.get("root_usage", snap["usage"][0]))
-        self.metrics.root_usage.append(root_usage)
-        self.metrics.overshoot_pages = max(
-            self.metrics.overshoot_pages, root_usage - self.pool_capacity)
-        usage, high = snap["usage"], snap["high"]
-        lim = high < D.UNLIMITED
-        if lim.any():
-            self.metrics.session_overshoot_pages = max(
-                self.metrics.session_overshoot_pages,
-                int((usage[lim] - high[lim]).max()))
+        tr = self.tracer
+        with tr.span("daemon.snapshot"):
+            snap = self.cg.snapshot()
+            # last known-good step-boundary snapshot: the rebuild-from-
+            # snapshot path (poisoned async daemon) restores from here
+            self._last_snapshot = snap
+            root_usage = int(snap.get("root_usage", snap["usage"][0]))
+            m = self.metrics
+            m.peak_pool_pages = max(m.peak_pool_pages, root_usage)
+            m.overshoot_pages = max(m.overshoot_pages,
+                                    root_usage - self.pool_capacity)
+            usage, high = snap["usage"], snap["high"]
+            lim = high < D.UNLIMITED
+            if lim.any():
+                m.session_overshoot_pages = max(
+                    m.session_overshoot_pages,
+                    int((usage[lim] - high[lim]).max()))
         # freeze under extreme pressure (graceful degradation step 2)
         if e.use_freeze and root_usage > e.freeze_threshold * self.pool_capacity:
             cands = [self.sessions[sid] for sid in self.slot_session
@@ -409,7 +435,8 @@ class Engine:
                      and self.sessions[sid].priority == D.LOW]
             if cands:
                 victim = max(cands, key=lambda s: s.pages)
-                self._freeze(victim)
+                with tr.span("daemon.freeze"):
+                    self._freeze(victim)
         else:
             frozen = [s for s in self.sessions.values()
                       if s.state is SState.FROZEN]
@@ -417,7 +444,8 @@ class Engine:
                 cand = min(frozen, key=lambda s: s.pages)
                 if (root_usage + cand.pages
                         < e.thaw_threshold * self.pool_capacity):
-                    self._thaw(cand)
+                    with tr.span("daemon.thaw"):
+                        self._thaw(cand)
         if self._adaptive is not None:
             # closed loop: poll every step boundary for synchronous
             # backends; for the async daemon, once per applied epoch —
@@ -426,11 +454,11 @@ class Engine:
             if epoch is None or epoch != self._adaptive_epoch:
                 self._adaptive_epoch = epoch
                 self._adaptive.poll(float(self.step_no))
-        self._try_admit()
+        with tr.span("daemon.admit"):
+            self._try_admit()
 
     def _freeze(self, s: Session) -> None:
-        if s.sid in self._lease:
-            self._lease.pop(s.sid).close()     # residual moves to session
+        self._close_lease(s)                   # residual moves to session
         self.caches.freeze_slot(s.sid, s.slot, pages=s.pages,
                                 meta={"length": s.length},
                                 now=self.step_no)
@@ -442,7 +470,7 @@ class Engine:
         s.state = SState.FROZEN
         s.n_freezes += 1
         self.metrics.n_freezes += 1
-        self.log.emit(self.step_no, Ev.FREEZE, s.domain, pages=s.pages)
+        self.tracer.begin("session.frozen", s.sid)
 
     def _thaw(self, s: Session) -> None:
         slot, meta = self.caches.thaw_slot(s.sid)
@@ -453,11 +481,10 @@ class Engine:
         self.slot_session[slot] = s.sid
         s.state = SState.RUNNING
         self.metrics.n_thaws += 1
-        self.log.emit(self.step_no, Ev.THAW, s.domain)
+        self.tracer.end("session.frozen", s.sid)
 
     def _finish(self, s: Session) -> None:
-        if s.sid in self._lease:
-            self._lease.pop(s.sid).close()
+        self._close_lease(s)
         self.cg.uncharge(s.domain, s.pages)
         self.cg.rmdir(s.domain, transfer_residual=False)
         self.caches.free_slot(s.slot)
@@ -465,13 +492,12 @@ class Engine:
         s.slot = -1
         s.state = SState.DONE
         s.t_done = self.step_no
-        self.log.emit(self.step_no, Ev.DONE, s.domain)
 
     def _evict(self, s: Session) -> None:
         """Last resort — the paper's triple-penalty path; counted so the
         benchmarks can show how rarely it fires."""
-        if s.sid in self._lease:
-            self._lease.pop(s.sid).close()
+        self._close_lease(s)
+        self.tracer.end("session.stalled", s.sid)
         self.cg.uncharge(s.domain, s.pages)
         self.cg.rmdir(s.domain, transfer_residual=False)
         if s.slot >= 0:
@@ -480,7 +506,6 @@ class Engine:
         s.state = SState.EVICTED
         s.t_done = self.step_no
         self.metrics.n_evictions += 1
-        self.log.emit(self.step_no, Ev.EVICT, s.domain)
 
     # ------------------------------------------------- daemon-fault recovery
 
@@ -508,11 +533,10 @@ class Engine:
         self._view = self.cg.device_view()
         self._step = _make_step_fn(self.cfg, self.perf, self.ecfg,
                                    self._view)
-        self._prev_throttle = np.asarray(
+        self._prev_throttle = to_host(
             self._view.state["throttle_until"]).reshape(-1).astype(
                 np.int64).copy()
         self.metrics.n_rebuilds += 1
-        self.log.emit(self.step_no, Ev.REBUILD, "/")
 
     def _reconcile_sessions(self) -> None:
         """The snapshot is up to one step-boundary stale: admissions,
@@ -537,6 +561,7 @@ class Engine:
                 # the lease postdates the snapshot: drop it rather than
                 # resurrect it — the next burst step re-declares
                 self._lease.pop(s.sid)
+                self.tracer.end("session.tool_call", s.sid)
                 self.cg.intent._open.pop(lease.path, None)
                 lease.closed = True
                 lease = None
@@ -557,7 +582,31 @@ class Engine:
     # ----------------------------------------------------------------- step
 
     def step(self) -> None:
-        e = self.ecfg
+        """One engine step, in phases that tile it (each a span when the
+        tracer is on): flush, inputs, dispatch, readback, bookkeeping,
+        daemon."""
+        tr = self.tracer
+        with tr.step(self.step_no):
+            with tr.span("engine.flush"):
+                self._flush()
+            with tr.span("engine.inputs"):
+                args, amt = self._inputs()
+            with tr.span("engine.dispatch"):
+                mode = ("inkernel" if self.ecfg.mode == "inkernel"
+                        else "userspace")
+                nxt, self.caches.state, new_ctrl, granted, _, finite = \
+                    self._step(*args, mode=mode)
+                self._view.commit(new_ctrl)
+            with tr.span("engine.readback"):
+                nxt, granted = self._readback(nxt, granted, finite)
+            with tr.span("engine.bookkeeping"):
+                self._bookkeeping(nxt, granted, amt)
+            with tr.span("engine.daemon"):
+                self._daemon()
+            self.step_no += 1
+            self.metrics.steps = self.step_no
+
+    def _flush(self) -> None:
         # epoch boundary: queued lifecycle ops (async backend) apply
         # here, before the step reads the control state — never between
         # the state read and the post-step commit.  A wedged/poisoned
@@ -569,7 +618,12 @@ class Engine:
             self.cg.flush()
         except DaemonError:
             self._rebuild_backend()
-        if self.ecfg.mode == "userspace":
+
+    def _inputs(self) -> tuple:
+        """The jitted step's positional arguments (the per-slot inputs on
+        the device) and the pages each slot charges."""
+        e = self.ecfg
+        if e.mode == "userspace":
             self._userspace_policy()
             self._apply_pending_gate()
         tokens = np.zeros(e.max_slots, np.int32)
@@ -588,22 +642,27 @@ class Engine:
             dom[slot] = s.dom_idx
             amt[slot] = self.accountant.crossing(s.length)
         self.key, sub = jax.random.split(self.key)
-        nxt, self.caches.state, new_ctrl, granted, stalled, finite = \
-            self._step(self.params, self.caches.state, self._view.state,
-                       jnp.asarray(tokens), jnp.asarray(lengths),
-                       jnp.asarray(dom), jnp.asarray(amt),
-                       jnp.asarray(self._host_gate), self.step_no, sub,
-                       mode=("inkernel" if e.mode == "inkernel"
-                             else "userspace"))
-        self._view.commit(new_ctrl)
-        nxt = np.asarray(nxt)
-        granted = np.asarray(granted)
-        self.metrics.nonfinite_logit_steps += int(not bool(finite))
+        return ((self.params, self.caches.state, self._view.state,
+                 jnp.asarray(tokens), jnp.asarray(lengths), jnp.asarray(dom),
+                 jnp.asarray(amt), jnp.asarray(self._host_gate),
+                 self.step_no, sub), amt)
+
+    def _readback(self, nxt, granted, finite) -> tuple:
+        """The step's outputs the host needs, read from the device."""
+        nxt = to_host(nxt)
+        granted = to_host(granted)
+        self.metrics.nonfinite_logit_steps += int(not bool(to_host(finite)))
         # throttle-trigger accounting (memcg_bpf_ops delay counter)
-        tu = np.asarray(self._view.state["throttle_until"]).reshape(-1)
+        tu = to_host(self._view.state["throttle_until"]).reshape(-1)
         self.metrics.throttle_triggers += int(np.sum(tu > self._prev_throttle))
         self._prev_throttle = np.maximum(tu, self._prev_throttle)
+        return nxt, granted
 
+    def _bookkeeping(self, nxt, granted, amt) -> None:
+        """Advance every granted session; stall, feed back to or evict
+        the denied ones."""
+        e = self.ecfg
+        tr = self.tracer
         for slot, sid in enumerate(self.slot_session):
             if sid is None:
                 continue
@@ -615,6 +674,7 @@ class Engine:
                     s.alloc_latencies_steps.append(
                         self.step_no - s.stall_started)
                     s.stall_started = None
+                    tr.end("session.stalled", sid)
                 elif amt[slot]:
                     s.alloc_latencies_steps.append(0)
                 s.pages += int(amt[slot])
@@ -625,6 +685,7 @@ class Engine:
                 s.stall_steps += 1
                 if s.stall_started is None:
                     s.stall_started = self.step_no
+                    tr.begin("session.stalled", sid)
                 stall = self.step_no - s.stall_started
                 # graduated feedback: first shrink the pending append;
                 # if the session is wedged against the pool wall, roll
@@ -642,18 +703,11 @@ class Engine:
                         if freed:
                             self.cg.uncharge(s.dom_idx, freed)
                         s.feedbacks.append(fb)
-                        self.log.emit(self.step_no, Ev.FEEDBACK, s.domain,
-                                      action="rollback", freed=freed)
                     else:
                         s.apply_feedback(fb, scale=0.5)
-                        self.log.emit(self.step_no, Ev.FEEDBACK, s.domain,
-                                      action="shrink")
                     self.metrics.n_feedbacks += 1
                 elif stall > e.evict_patience_steps:
                     self._evict(s)
-        self._daemon()
-        self.step_no += 1
-        self.metrics.steps = self.step_no
 
     def close(self) -> None:
         """Release backend resources — stops the async lifecycle daemon
@@ -704,6 +758,6 @@ class Engine:
             "feedbacks": self.metrics.n_feedbacks,
             "overshoot_pages": self.metrics.overshoot_pages,
             "session_overshoot_pages": self.metrics.session_overshoot_pages,
-            "peak_pool_pages": max(self.metrics.root_usage, default=0),
+            "peak_pool_pages": self.metrics.peak_pool_pages,
             "nonfinite_logit_steps": self.metrics.nonfinite_logit_steps,
         }

@@ -21,12 +21,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.freezer import FrozenStore
 from repro.models import model as M
 from repro.models.schema import Leaf, tree_map_schema
+from repro.spans import OFF, Tracer, to_host
 
 
 @dataclass(frozen=True)
@@ -44,8 +44,10 @@ class PageAccountant:
 class SlotCaches:
     """Dense per-slot decode state with host offload."""
 
-    def __init__(self, cfg: ModelConfig, max_slots: int, s_max: int):
+    def __init__(self, cfg: ModelConfig, max_slots: int, s_max: int, *,
+                 tracer: Optional[Tracer] = None):
         self.cfg = cfg
+        self.tracer = tracer if tracer is not None else OFF
         self.max_slots = max_slots
         self.s_max = s_max
         sch = M.decode_state_schema(cfg, max_slots, s_max)
@@ -61,9 +63,11 @@ class SlotCaches:
 
     def free_slot(self, slot: int) -> None:
         # zero the slot's state so a recycled slot starts clean
-        self.state = jax.tree.map(
-            lambda x: x.at[:, slot].set(jnp.zeros_like(x[:, slot])), self.state)
-        self._free.append(slot)
+        with self.tracer.span("kv.free_slot"):
+            self.state = jax.tree.map(
+                lambda x: x.at[:, slot].set(jnp.zeros_like(x[:, slot])),
+                self.state)
+            self._free.append(slot)
 
     @property
     def n_free(self) -> int:
@@ -74,16 +78,19 @@ class SlotCaches:
     def freeze_slot(self, session_id: str, slot: int, *, pages: int,
                     meta: Optional[dict] = None, now: float = 0.0) -> None:
         """Offload one slot's state to host memory and recycle the slot."""
-        blob = jax.tree.map(lambda x: np.asarray(x[:, slot]), self.state)
-        self.store.freeze(session_id, blob, pages=pages, meta=meta, now=now)
-        self.free_slot(slot)
+        with self.tracer.span("kv.freeze_slot"):
+            blob = jax.tree.map(lambda x: to_host(x[:, slot]), self.state)
+            self.store.freeze(session_id, blob, pages=pages, meta=meta,
+                              now=now)
+            self.free_slot(slot)
 
     def thaw_slot(self, session_id: str) -> tuple[int, dict]:
         """Restore a frozen session into a fresh slot."""
-        slot = self.alloc_slot()
-        assert slot is not None, "no free slot to thaw into"
-        entry = self.store.thaw(session_id)
-        self.state = jax.tree.map(
-            lambda x, b: x.at[:, slot].set(jnp.asarray(b, x.dtype)),
-            self.state, entry.blobs)
-        return slot, entry.meta
+        with self.tracer.span("kv.thaw_slot"):
+            slot = self.alloc_slot()
+            assert slot is not None, "no free slot to thaw into"
+            entry = self.store.thaw(session_id)
+            self.state = jax.tree.map(
+                lambda x, b: x.at[:, slot].set(jnp.asarray(b, x.dtype)),
+                self.state, entry.blobs)
+            return slot, entry.meta
