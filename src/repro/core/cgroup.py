@@ -685,20 +685,12 @@ class DeviceTableBackend:
         return DeviceView(self)
 
     def _recompute_flat(self) -> None:
-        """Re-flatten hierarchical weights into the device row
-        (lifecycle rate — one host sync, like the other lifecycle ops),
-        scx_flatcg style."""
+        """Re-flatten hierarchical weights into the device row from the
+        table's host mirror of ``cpu.weight``, scx_flatcg style (mkdir
+        and rmdir pass the row inside their own compiled edit)."""
         import jax.numpy as jnp
-
-        from repro.core.sched import flat_weights_by_path
-        st = self.table.state
-        w = to_host(st["weight"])
-        flat = flat_weights_by_path(
-            {p: int(w[i]) for p, i in self.table.index.items()})
-        arr = np.zeros((self.table.n,), np.float32)
-        for p, i in self.table.index.items():
-            arr[i] = flat[p]
-        self.table.state = dict(st, flat_weight=jnp.asarray(arr))
+        self.table.state = dict(self.table.state,
+                                flat_weight=jnp.asarray(self.table.flat_row()))
 
     # lifecycle
     def mkdir(self, path: str, spec: DomainSpec) -> int:
@@ -706,20 +698,16 @@ class DeviceTableBackend:
         idx = self.table.create(path, high=spec.high, max=spec.max,
                                 low=spec.low, priority=spec.priority,
                                 weight=spec.weight, cpu_max=spec.cpu_max)
-        self._recompute_flat()
         self.log.emit(self._now, Ev.CREATE, path, high=spec.high,
                       max=spec.max)
         return idx
 
     def rmdir(self, path: str, transfer_residual: bool) -> int:
-        residual = self.table.usage(path)
-        parent = parent_path(path)
-        self.table.remove(path)          # uncharges residual from the chain
-        if transfer_residual and residual and parent is not None:
-            self.charge_unchecked(parent, residual)
-        self._recompute_flat()
+        # one compiled edit releases the residual from the chain (and
+        # charges it to the parent's); the only read is the residual
+        residual = self.table.remove(path, transfer=transfer_residual)
         self.log.emit(self._now, Ev.REMOVE, path)
-        return residual
+        return int(to_host(residual))
 
     def exists(self, path: str) -> bool:
         return path in self.table.index
@@ -755,17 +743,10 @@ class DeviceTableBackend:
                             delay_ms=window * self.table.prog.step_ms)
 
     def uncharge(self, path: str, pages: int) -> None:
-        import jax.numpy as jnp
-        from repro.core import controller as C
-        idx = self.table.index[path]
-        self.table.state = C.uncharge_batch(
-            self.table.state, jnp.array([idx], jnp.int32),
-            jnp.array([pages], jnp.int32))
+        self.table.uncharge(path, pages)
 
     def charge_unchecked(self, path: str, pages: int) -> None:
-        from repro.core import controller as C
-        self.table.state = C.host_charge(self.table.state,
-                                         self.table.index[path], pages)
+        self.table.charge_unchecked(path, pages)
 
     # scheduling (host-driven path; the engine schedules in-step via
     # device_view().schedule)
@@ -854,6 +835,7 @@ class DeviceTableBackend:
         self.table.state = dict(
             st, **{key: st[key].at[idx].set(int(value))})
         if file == "cpu.weight":
+            self.table.weights[path] = int(value)
             self._recompute_flat()
 
     def snapshot(self) -> dict:
@@ -893,6 +875,9 @@ class DeviceTableBackend:
         t = self.table
         assert len(snap["usage"]) == t.n, "snapshot/table shape mismatch"
         t.index = dict(snap["index"])
+        w = snap.get("weight")
+        t.weights = {p: D.DEFAULT_WEIGHT if w is None else int(w[i])
+                     for p, i in t.index.items()}
         used = set(t.index.values())
         t._free = [i for i in range(1, t.n) if i not in used]
         heapq.heapify(t._free)

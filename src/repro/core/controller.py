@@ -31,6 +31,7 @@ results are deterministic and order-faithful.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import heapq
 from dataclasses import dataclass
 from typing import Optional
@@ -45,7 +46,7 @@ from repro.core.progs import (ChainView, PolicyProgram, Request, as_program,
                               as_programs, charge_decision, check_registry,
                               gate_decision, pad_row, path_in_scope,
                               registry_unknown_params, registry_width)
-from repro.spans import to_host
+from repro.spans import note, to_host
 
 UNLIMITED = D.UNLIMITED
 DEPTH = 4          # root / tenant / session / tool-call
@@ -226,6 +227,109 @@ def uncharge_batch(state: dict, dom: jax.Array, amt: jax.Array):
     return dict(state, usage=jnp.maximum(usage, 0))
 
 
+# ------------------------------------------------------ host-path table edits
+#
+# Each lifecycle edit the host makes to the table is one compiled program
+# that reads nothing back. Indices and a spec's scalars arrive as one
+# traced int32 vector, so no index, limit or priority value retraces;
+# ``flat`` is the whole ``flat_weight`` row, re-flattened on the host
+# from its mirror of ``cpu.weight`` (``DeviceDomainTable.flat_row``).
+
+
+def _charge_chain(state: dict, idx, amt) -> dict:
+    """``host_charge``'s arithmetic, traced: ``max(0, usage + amt)`` along
+    the chain of ``idx`` (nothing for ``idx`` < 0), then ``peak = max(peak,
+    usage)`` over the whole table."""
+    chain = _ancestor_chain(state["parent"], jnp.maximum(idx, 0))
+    valid = (chain >= 0) & (idx >= 0)
+    cidx = jnp.maximum(chain, 0)
+    usage = state["usage"]
+    delta = jnp.where(valid, jnp.maximum(0, usage[cidx] + amt) - usage[cidx],
+                      0)
+    usage = usage.at[cidx].add(delta)
+    return dict(state, usage=usage, peak=jnp.maximum(state["peak"], usage))
+
+
+@jax.jit
+def create_row(state: dict, ints: jax.Array, flat: jax.Array) -> dict:
+    """Configure row ``ints[0]`` as a fresh child of row ``ints[1]``;
+    ``ints[2:]`` is (high, max, low, priority, weight, cpu_max). The child
+    inherits its parent's live program row and slot, copied on the device
+    (cgroup settings propagate down: a child made after a subtree attach
+    runs the subtree's program)."""
+    idx, pidx = ints[0], ints[1]
+    high, mx, low, priority, weight, cpu_max = (ints[k] for k in range(2, 8))
+    st = state
+    return dict(
+        st,
+        high=st["high"].at[idx].set(high),
+        max=st["max"].at[idx].set(mx),
+        low=st["low"].at[idx].set(low),
+        parent=st["parent"].at[idx].set(pidx),
+        priority=st["priority"].at[idx].set(priority),
+        usage=st["usage"].at[idx].set(0),
+        peak=st["peak"].at[idx].set(0),
+        frozen=st["frozen"].at[idx].set(False),
+        active=st["active"].at[idx].set(True),
+        throttle_until=st["throttle_until"].at[idx].set(0),
+        prog=st["prog"].at[idx].set(st["prog"][pidx]),
+        prog_id=st["prog_id"].at[idx].set(st["prog_id"][pidx]),
+        weight=st["weight"].at[idx].set(weight),
+        cpu_max=st["cpu_max"].at[idx].set(cpu_max),
+        flat_weight=flat,
+        vruntime=st["vruntime"].at[idx].set(0.0),
+        cpu_used=st["cpu_used"].at[idx].set(0),
+        cpu_stamp=st["cpu_stamp"].at[idx].set(-1),
+        mem_stall=st["mem_stall"].at[idx].set(0),
+        cpu_stall=st["cpu_stall"].at[idx].set(0),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("transfer",))
+def remove_row(state: dict, idx: jax.Array, flat: jax.Array, *,
+               transfer: bool):
+    """Retire row ``idx``: release its residual usage up the ancestor
+    chain (``uncharge_batch``'s arithmetic, clamped at 0), with
+    ``transfer`` charge it to the parent's chain as ``host_charge`` does,
+    and reset the row. Returns (state, residual as a device scalar)."""
+    usage = state["usage"]
+    residual = usage[idx]
+    moved = residual != 0
+    released = uncharge_batch(state, idx.reshape(1), residual.reshape(1))
+    st = dict(state, usage=jnp.where(moved, released["usage"], usage))
+    if transfer:
+        charged = _charge_chain(st, state["parent"][idx], residual)
+        st = dict(st, usage=jnp.where(moved, charged["usage"], st["usage"]),
+                  peak=jnp.where(moved, charged["peak"], st["peak"]))
+    return dict(
+        st,
+        active=st["active"].at[idx].set(False),
+        frozen=st["frozen"].at[idx].set(False),
+        parent=st["parent"].at[idx].set(-1),
+        weight=st["weight"].at[idx].set(D.DEFAULT_WEIGHT),
+        cpu_max=st["cpu_max"].at[idx].set(UNLIMITED),
+        flat_weight=flat,
+        vruntime=st["vruntime"].at[idx].set(0.0),
+        cpu_used=st["cpu_used"].at[idx].set(0),
+        cpu_stamp=st["cpu_stamp"].at[idx].set(-1),
+        mem_stall=st["mem_stall"].at[idx].set(0),
+        cpu_stall=st["cpu_stall"].at[idx].set(0),
+        prog_id=st["prog_id"].at[idx].set(0),
+    ), residual
+
+
+@jax.jit
+def charge_unchecked_row(state: dict, ints: jax.Array) -> dict:
+    """``host_charge`` of ``ints[1]`` pages to domain ``ints[0]``."""
+    return _charge_chain(state, ints[0], ints[1])
+
+
+@jax.jit
+def uncharge_row(state: dict, ints: jax.Array) -> dict:
+    """``uncharge_batch`` of ``ints[1]`` pages from domain ``ints[0]``."""
+    return uncharge_batch(state, ints[:1], ints[1:])
+
+
 def slot_gate(state: dict, slot_dom: jax.Array, step, prog=None) -> jax.Array:
     """May each slot advance this step?  Dispatches ``on_gate`` of the
     slot's domain program (default: no frozen/throttled ancestor)."""
@@ -259,6 +363,8 @@ class DeviceDomainTable:
         self.scopes = ["/"] * len(self.progs)
         self.state = new_state(capacity_pages, n_domains, self.progs)
         self.index: dict[str, int] = {"/": 0}
+        # each live path's cpu.weight: only the host writes that column
+        self.weights: dict[str, int] = {"/": D.DEFAULT_WEIGHT}
         self._free = list(range(1, n_domains))   # heap: lowest index first
 
     # ------------------------------------------------------------ programs
@@ -336,17 +442,18 @@ class DeviceDomainTable:
                     prog = prog.at[idx, pr.col(k)].set(float(v))
         self.state = dict(self.state, prog=prog)
 
-    def _fresh_row(self, path: str, pidx: int) -> np.ndarray:
-        """New domains inherit their parent's live row (cgroup settings
-        propagate down) — and, with ``_fresh_prog_id``, the parent's
-        program slot: a child created after a subtree attach runs the
-        subtree's program, not the root default."""
-        return to_host(self.state["prog"][pidx])
-
-    def _fresh_prog_id(self, pidx: int) -> int:
-        return int(to_host(self.state["prog_id"][pidx]))
-
     # ------------------------------------------------------------ lifecycle
+
+    def flat_row(self) -> np.ndarray:
+        """The ``flat_weight`` row, re-flattened scx_flatcg style from the
+        host's mirror of every live domain's ``cpu.weight``."""
+        # imported here: core/sched.py imports this module
+        from repro.core.sched import flat_weights_by_path
+        flat = flat_weights_by_path({p: self.weights[p] for p in self.index})
+        row = np.zeros((self.n,), np.float32)
+        for p, i in self.index.items():
+            row[i] = flat[p]
+        return row
 
     def create(self, path: str, *, high: int = UNLIMITED, max: int = UNLIMITED,
                low: int = 0, priority: int = D.NORMAL,
@@ -355,57 +462,39 @@ class DeviceDomainTable:
         assert path not in self.index, path
         parent_path = path.rsplit("/", 1)[0] or "/"
         pidx = self.index[parent_path]
-        idx = heapq.heappop(self._free)
+        idx = self._free[0]
+        ints = np.array([idx, pidx, high, max, low, priority, weight,
+                         cpu_max], np.int32)
+        heapq.heappop(self._free)
         self.index[path] = idx
-        st = self.state
-        self.state = dict(
-            st,
-            high=st["high"].at[idx].set(high),
-            max=st["max"].at[idx].set(max),
-            low=st["low"].at[idx].set(low),
-            parent=st["parent"].at[idx].set(pidx),
-            priority=st["priority"].at[idx].set(priority),
-            usage=st["usage"].at[idx].set(0),
-            peak=st["peak"].at[idx].set(0),
-            frozen=st["frozen"].at[idx].set(False),
-            active=st["active"].at[idx].set(True),
-            throttle_until=st["throttle_until"].at[idx].set(0),
-            prog=st["prog"].at[idx].set(
-                jnp.asarray(self._fresh_row(path, pidx))),
-            prog_id=st["prog_id"].at[idx].set(self._fresh_prog_id(pidx)),
-            weight=st["weight"].at[idx].set(weight),
-            cpu_max=st["cpu_max"].at[idx].set(cpu_max),
-            flat_weight=st["flat_weight"].at[idx].set(0.0),
-            vruntime=st["vruntime"].at[idx].set(0.0),
-            cpu_used=st["cpu_used"].at[idx].set(0),
-            cpu_stamp=st["cpu_stamp"].at[idx].set(-1),
-            mem_stall=st["mem_stall"].at[idx].set(0),
-            cpu_stall=st["cpu_stall"].at[idx].set(0),
-        )
+        self.weights[path] = int(ints[6])
+        note("table_update")
+        self.state = create_row(self.state, ints, self.flat_row())
         return idx
 
-    def remove(self, path: str) -> None:
+    def remove(self, path: str, *, transfer: bool) -> jax.Array:
+        """Retire ``path``; its residual usage leaves the ancestor chain
+        and, with ``transfer``, is charged to the parent's. Returns the
+        residual as a device scalar: reading it is the caller's choice."""
         idx = self.index.pop(path)
-        residual = int(to_host(self.state["usage"][idx]))
-        if residual:
-            # release residual charges up the chain (host-side lifecycle op)
-            self.state = uncharge_batch(self.state,
-                                        jnp.array([idx], jnp.int32),
-                                        jnp.array([residual], jnp.int32))
-        st = self.state
-        self.state = dict(st, active=st["active"].at[idx].set(False),
-                          frozen=st["frozen"].at[idx].set(False),
-                          parent=st["parent"].at[idx].set(-1),
-                          weight=st["weight"].at[idx].set(D.DEFAULT_WEIGHT),
-                          cpu_max=st["cpu_max"].at[idx].set(UNLIMITED),
-                          flat_weight=st["flat_weight"].at[idx].set(0.0),
-                          vruntime=st["vruntime"].at[idx].set(0.0),
-                          cpu_used=st["cpu_used"].at[idx].set(0),
-                          cpu_stamp=st["cpu_stamp"].at[idx].set(-1),
-                          mem_stall=st["mem_stall"].at[idx].set(0),
-                          cpu_stall=st["cpu_stall"].at[idx].set(0),
-                          prog_id=st["prog_id"].at[idx].set(0))
+        del self.weights[path]
+        note("table_update")
+        self.state, residual = remove_row(self.state, np.int32(idx),
+                                          self.flat_row(),
+                                          transfer=transfer)
         heapq.heappush(self._free, idx)
+        return residual
+
+    def charge_unchecked(self, path: str, pages: int) -> None:
+        """Unconditional hierarchical charge (``host_charge``)."""
+        note("table_update")
+        self.state = charge_unchecked_row(
+            self.state, np.array([self.index[path], pages], np.int32))
+
+    def uncharge(self, path: str, pages: int) -> None:
+        note("table_update")
+        self.state = uncharge_row(
+            self.state, np.array([self.index[path], pages], np.int32))
 
     def set_frozen(self, path: str, flag: bool) -> None:
         idx = self.index[path]

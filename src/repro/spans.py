@@ -20,8 +20,10 @@ Three kinds of record:
   cross steps (queued, tool call, stalled, frozen). They have no
   profiler annotation, because a profiler scope has to nest;
 * per-step counters (``count``): ``host_sync`` counts device-to-host
-  reads (every one goes through ``to_host``). Counts made between two
-  steps go to the next step.
+  reads (every one goes through ``to_host``); ``table_update`` counts
+  the compiled lifecycle edits of the device domain table
+  (``core/controller.py``). Counts made between two steps go to the
+  next step.
 
 Spans are recorded on the calling thread only: on the async backend the
 daemon thread's work shows up as the engine's wait in ``engine.flush``.
@@ -60,7 +62,8 @@ class Span(NamedTuple):
 
 
 # the tracer whose span is open on this thread (and context), for
-# ``to_host``: the backends that read the device do not hold a tracer.
+# ``note`` and ``to_host``: the backends that read and edit the device do
+# not hold a tracer.
 # Each span sets it on entry and restores the previous value on exit, so
 # it names the innermost open span's tracer and nothing once the
 # outermost span closes. Several engines on one thread therefore count
@@ -71,12 +74,18 @@ _ACTIVE: contextvars.ContextVar = contextvars.ContextVar(
     "agentcgroup_tracer", default=None)
 
 
+def note(name: str) -> None:
+    """Count ``name`` once on the tracer whose span is open on this
+    thread; with none open, do nothing."""
+    t = _ACTIVE.get()
+    if t is not None:
+        t.count(name)
+
+
 def to_host(x) -> np.ndarray:
     """``np.asarray(x)``: one device-to-host read, counted as
     ``host_sync`` by the tracer whose span is open on this thread."""
-    t = _ACTIVE.get()
-    if t is not None:
-        t.count("host_sync")
+    note("host_sync")
     return np.asarray(x)
 
 
