@@ -43,19 +43,17 @@ def check_cell(name: str, topo) -> None:
     from repro.models.schema import tree_map_schema
     from repro.serving.engine import EngineConfig, _make_step_fn
 
-    from bench import run
-    from bench.costs import dims
-    from bench.references import dense
-    from bench.weights import LAYER_LEAVES
-    from repro.perf import DEFAULT_PERF, replace as perf_replace
+    from bench import check, run
+    from bench.models import family
 
     cell, m, mix, _, _ = run.load_cell(name)
+    fam = family(m)
     one = SingleDeviceSharding(topo.devices[0])
 
     def sds(shape, dtype, sharding=one):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
                                     sharding=sharding)
-    cfg = run.model_config(m)
+    cfg = fam.model_config(m)
     ecfg = EngineConfig(**mix["engine"])
     compat.on_tpu = lambda: True
     n_domains = 4 * ecfg.max_slots + 8
@@ -79,8 +77,7 @@ def check_cell(name: str, topo) -> None:
         view = AgentCgroup(DeviceTableBackend(
             ecfg.pool_pages, n_domains=n_domains)).device_view()
         ctrl = jax.tree.map(lambda x: sds(x.shape, x.dtype), view.state)
-    step = _make_step_fn(cfg, perf_replace(DEFAULT_PERF, scan_chunk=32),
-                         ecfg, view)
+    step = _make_step_fn(cfg, fam.perf(m), ecfg, view)
     B = ecfg.max_slots
     params = tree_map_schema(
         lambda l: sds(l.shape, l.dtype or cfg.dtype, weights),
@@ -96,11 +93,12 @@ def check_cell(name: str, topo) -> None:
                    for ln in c.as_text().splitlines())
     print(f"{name}: engine step {used(c)!r} GiB per chip, flash_decode calls "
           f"{n_kernel}", flush=True)
-    x = dims(m)
-    S = -(-ecfg.s_max // dense.Q_BLOCK) * dense.Q_BLOCK
-    w = {k: sds(shp(x), "float32") for k, (shp, _) in LAYER_LEAVES.items()}
+    ref = check.reference_module(m)
+    x = fam.dims(m)
+    S = -(-ecfg.s_max // ref.Q_BLOCK) * ref.Q_BLOCK
+    w = {k: sds(shp(x), "float32") for k, (shp, _) in fam.LAYER_LEAVES.items()}
     with jax.default_matmul_precision("highest"):
-        c = jax.jit(lambda h, w: dense.layer(m, h, w, dense._ident)).lower(
+        c = jax.jit(lambda h, w: ref.layer(m, h, w, ref._ident)).lower(
             sds((S, x["d"]), "float32"), w).compile()
     print(f"{name}: reference layer at {S} positions {used(c)!r} GiB",
           flush=True)

@@ -4,8 +4,10 @@ Pre-norm RMSNorm, rotary embedding on q and k (the half-split convention,
 frequencies theta**(-i/half)), grouped-query causal attention with scale
 head_dim**-0.5, SwiGLU MLP, final RMSNorm and the (tied or untied) head over
 the padded vocabulary. It imports nothing of the program and makes its own
-weights from the seed (``bench/weights.py``). Run layer by layer so that it
-fits beside nothing: every matrix product at ``highest`` precision.
+weights from the seed through the dense family's leaves
+(``bench/models/dense.py``, which imports the program only inside the
+functions that build it). Run layer by layer so that it fits beside
+nothing: every matrix product at ``highest`` precision.
 
 ``quant`` replaces the identity at the inputs of every matrix product and at
 the stored K and V: the reference with ``quant`` set is the lower-precision
@@ -19,8 +21,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights as W
-from bench.costs import dims
+from bench.models.dense import dims, global_weights, layer_weights
+from bench.weights import root_key
 
 Q_BLOCK = 256
 
@@ -89,12 +91,12 @@ def logits_at(m, seed: int, seqs: list, served_dtype, quant=_ident,
     causal mask keeps from every position that counts.
     """
     x = dims(m)
-    key = W.root_key(seed)
+    key = root_key(seed)
     S = max(pad_to, max(len(t) for t, _ in seqs))
     S = -(-S // Q_BLOCK) * Q_BLOCK
     f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
     with jax.default_matmul_precision("highest"):
-        g = jax.jit(lambda k: W.global_weights(m, k, served_dtype))(key)
+        g = jax.jit(lambda k: global_weights(m, k, served_dtype))(key)
         tok = f32(g["tok"])
         hs = []
         for toks, _ in seqs:
@@ -102,7 +104,7 @@ def logits_at(m, seed: int, seqs: list, served_dtype, quant=_ident,
             ids[:len(toks)] = toks
             hs.append(tok[jnp.asarray(ids)])
         mk = jax.jit(lambda k, l: jax.tree.map(
-            f32, W.layer_weights(m, k, l, served_dtype)))
+            f32, layer_weights(m, k, l, served_dtype)))
         run = jax.jit(lambda h, w: layer(m, h, w, quant))
         for l in range(x["layers"]):
             w = mk(key, l)

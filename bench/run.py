@@ -2,7 +2,8 @@
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-The cell names a configuration (``bench/configs/<config>.json``) and a mix
+The cell names a configuration (``bench/configs/<config>.json``, built by
+the model family it names, ``bench/models/<family>.py``) and a mix
 (``bench/traffic/<traffic>.json``); its metrics are read by
 ``bench/metrics/<metric>.py``. Set-up (weights from the seed on the device,
 the engine, the compiled step, ``warmup_steps`` engine steps of the fleet)
@@ -62,41 +63,18 @@ def load_cell(workload: str, bench_file: Path = ROOT / "BENCHMARK.json"):
     return cell, model, mix, mine(spec["end_to_end"]), mine(spec["per_layer"])
 
 
-def model_config(m: dict):
-    """The program's ModelConfig for a dense decoder configuration file."""
-    from repro.configs.base import ModelConfig
-    return ModelConfig(
-        name=m["name"], family="dense", n_layers=m["num_hidden_layers"],
-        d_model=m["hidden_size"], n_heads=m["num_attention_heads"],
-        n_kv_heads=m["num_key_value_heads"], d_ff=m["intermediate_size"],
-        vocab=m["vocab_size"], head_dim=m["head_dim"],
-        rope_theta=m["rope_theta"], norm_eps=m["rms_norm_eps"],
-        tie_embeddings=m["tie_word_embeddings"], dtype=m["torch_dtype"])
-
-
-def program_params(m: dict, seed: int, dtype):
-    """The program's weight tree, made on the device in one jitted call
-    from ``bench/weights.py``."""
-    import jax
+def build_engine(m: dict, mix: dict, seed: int, **kw):
+    """The engine for configuration ``m`` under mix ``mix``: its config,
+    weights (made from ``seed`` on the device) and engine settings come
+    from the configuration's family (``bench/models``)."""
     import jax.numpy as jnp
-    from bench import weights as W
-
-    def make(key):
-        g = W.global_weights(m, key, dtype)
-        per = jax.vmap(lambda l: W.layer_weights(m, key, l, dtype))(
-            jnp.arange(m["num_hidden_layers"]))
-        embed = {"tok": g["tok"]}
-        if "head" in g:
-            embed["head"] = g["head"]
-        layer = {"ln1": {"scale": per["ln1"]},
-                 "mixer": {n: per[n] for n in ("wq", "wk", "wv", "wo")},
-                 "ln2": {"scale": per["ln2"]},
-                 "ffn": {n: per[n] for n in ("w_gate", "w_up", "w_down")}}
-        return {"embed": embed, "groups": [layer],
-                "out_norm": {"scale": g["out_norm"]}}
-    # the key is an argument, not a constant, so that the compiled program
-    # is the same for every seed and comes from the compile cache
-    return jax.block_until_ready(jax.jit(make)(W.root_key(seed)))
+    from bench.models import family
+    from repro.serving.engine import Engine, EngineConfig
+    fam = family(m)
+    cfg = fam.model_config(m)
+    params = fam.program_params(m, seed, jnp.dtype(cfg.dtype))
+    return Engine(cfg, params, perf=fam.perf(m),
+                  ecfg=EngineConfig(**mix["engine"]), seed=seed, **kw)
 
 
 class Spans:
@@ -279,8 +257,6 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     from bench import check
     from bench.fleet import Fleet
     from bench.peaks import peaks
-    from repro.perf import DEFAULT_PERF, replace as perf_replace
-    from repro.serving.engine import Engine, EngineConfig
 
     cell, m, mix, e2e, per_layer = loaded or load_cell(workload)
     devices = jax.devices()
@@ -296,13 +272,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     counter = CompileCounter()
     peak = peaks(devices[0].device_kind) if require_tpu else {}
-    cfg = model_config(m)
-    params = program_params(m, seed, jnp.dtype(cfg.dtype))
     t_w = time.perf_counter()
-    eng = Engine(cfg, params, perf=perf_replace(DEFAULT_PERF, scan_chunk=32),
-                 ecfg=EngineConfig(**mix["engine"]), seed=seed)
-    del params
-    fleet = Fleet(eng, mix, seed, cfg.padded_vocab)
+    eng = build_engine(m, mix, seed)
+    dtype = jnp.dtype(eng.cfg.dtype)
+    fleet = Fleet(eng, mix, seed, eng.cfg.padded_vocab)
     spans = Spans(trace)
     control = check.record_control(eng)
     instrument(eng, fleet, spans, control)
@@ -311,8 +284,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     for _ in range(mix["warmup_steps"]):
         fleet_step(fleet, spans)
     setup_s = time.perf_counter() - T0
-    log(f"set-up {setup_s!r} s (engine and warm-up {time.perf_counter() - t_w!r}"
-        f" s; {counter.lowerings} lowerings, compile cache {counter.hits} "
+    log(f"set-up {setup_s!r} s (weights, engine and warm-up "
+        f"{time.perf_counter() - t_w!r} s; {counter.lowerings} lowerings, "
+        f"compile cache {counter.hits} "
         f"hits / {counter.misses} misses, compiling or loading "
         f"{counter.compile_s!r} s)")
 
@@ -377,8 +351,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     if ctl:
         log("control plane against the reference: " + json.dumps(ctl))
     if picked:
-        gaps = check.logit_gaps(m, seed, picked, jnp.dtype(cfg.dtype), s_max,
-                                controls)
+        gaps = check.logit_gaps(m, seed, picked, dtype, s_max, controls)
         readings = {k: float(v.max()) for k, v in gaps.items()}
         checks["logit_gap"] = (readings["program"],
                                m["check"]["logit_gap_limit"])

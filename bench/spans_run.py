@@ -91,12 +91,9 @@ def run_program_cell(workload: str, seed: int, seconds: float, trace: bool,
                      require_tpu: bool = True, keep=None,
                      trace_steps=None) -> dict:
     import jax
-    import jax.numpy as jnp
     from bench import check
     from bench import program_trace as PT
     from bench.fleet import Fleet
-    from repro.perf import DEFAULT_PERF, replace as perf_replace
-    from repro.serving.engine import Engine, EngineConfig
     from repro.spans import Tracer
 
     cell, m, mix, _, _ = loaded or R.load_cell(workload)
@@ -112,14 +109,10 @@ def run_program_cell(workload: str, seed: int, seconds: float, trace: bool,
                           or str(ROOT / ".jax_cache"))
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     counter = R.CompileCounter()
-    cfg = R.model_config(m)
-    params = R.program_params(m, seed, jnp.dtype(cfg.dtype))
     tr = Tracer(on=tracer)
-    eng = Engine(cfg, params, perf=perf_replace(DEFAULT_PERF, scan_chunk=32),
-                 ecfg=EngineConfig(**mix["engine"]), seed=seed, tracer=tr)
-    del params
+    eng = R.build_engine(m, mix, seed, tracer=tr)
     jitted = eng._step
-    fleet = Fleet(eng, mix, seed, cfg.padded_vocab)
+    fleet = Fleet(eng, mix, seed, eng.cfg.padded_vocab)
     spans = R.Spans(trace)
     control = check.record_control(eng)
     R.instrument(eng, fleet, spans, control)

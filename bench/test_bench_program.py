@@ -109,14 +109,8 @@ def test_idle_gaps_go_to_the_innermost_span_covering_most():
 
 @pytest.fixture(scope="module")
 def tiny_engine():
-    import jax.numpy as jnp
-    from repro.perf import DEFAULT_PERF, replace as perf_replace
-    from repro.serving.engine import Engine, EngineConfig
     _, m, mix, _, _ = tiny_cell()
-    cfg = run.model_config(m)
-    params = run.program_params(m, 3, jnp.dtype(cfg.dtype))
-    return Engine(cfg, params, perf=perf_replace(DEFAULT_PERF, scan_chunk=32),
-                  ecfg=EngineConfig(**mix["engine"]), seed=3)
+    return run.build_engine(m, mix, 3)
 
 
 def test_step_scopes_map_the_gate_select_and_the_charge(tiny_engine):

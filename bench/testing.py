@@ -10,9 +10,10 @@ ROOT = Path(__file__).resolve().parent.parent
 
 def tiny_model(limit: float = 1e-3) -> dict:
     """The program's reduced float32 preset's widths, at two layers."""
-    return {"name": "tiny", "reference": "dense", "hidden_size": 128,
-            "intermediate_size": 256, "num_attention_heads": 4,
-            "num_key_value_heads": 2, "head_dim": 32, "num_hidden_layers": 2,
+    return {"name": "tiny", "family": "dense", "reference": "dense",
+            "hidden_size": 128, "intermediate_size": 256,
+            "num_attention_heads": 4, "num_key_value_heads": 2,
+            "head_dim": 32, "num_hidden_layers": 2,
             "vocab_size": 500, "vocab_padded": 512, "rope_theta": 10000.0,
             "rms_norm_eps": 1e-5, "tie_word_embeddings": False,
             "torch_dtype": "float32", "check": {"logit_gap_limit": limit}}
